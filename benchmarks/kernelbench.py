@@ -1222,5 +1222,7 @@ if __name__ == "__main__":
                     help="tiny shapes, no timing, no BENCH_*.json writes — "
                          "kernel dispatch gate for CI")
     args = ap.parse_args()
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     print("name,us_per_call,derived")
     run(smoke=args.smoke)

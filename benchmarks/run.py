@@ -7,6 +7,8 @@ import traceback
 
 
 def main() -> None:
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     from benchmarks import (fig2_accuracy, fig3_intra, fig5_mean,
                             fig6_median, fig7_kmeans, fig8_ssabe,
                             fig9_sampling, fig10_delta, kernelbench,

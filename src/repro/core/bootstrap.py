@@ -268,8 +268,6 @@ def sharded_fused_states(stat: Statistic, base_seed, x2: jax.Array, B: int,
         return (states, est) if with_estimate else states
 
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map_compat
-    shard_map, sm_kw = shard_map_compat()
 
     def shard_fn(x_local, seed, step_):
         i = jax.lax.axis_index(data_axis)
@@ -283,9 +281,10 @@ def sharded_fused_states(stat: Statistic, base_seed, x2: jax.Array, B: int,
                     stat.psum_state(est, data_axis))
         return stat.psum_state(st, data_axis)
 
-    fn = shard_map(shard_fn, mesh=mesh,
-                   in_specs=(P(data_axis, None), P(), P()),
-                   out_specs=(P(), P()) if with_estimate else P(), **sm_kw)
+    fn = jax.shard_map(shard_fn, mesh=mesh,
+                       in_specs=(P(data_axis, None), P(), P()),
+                       out_specs=(P(), P()) if with_estimate else P(),
+                       check_vma=False)
     return fn(xp, jnp.asarray(base_seed, jnp.int32),
               jnp.asarray(step, jnp.int32))
 

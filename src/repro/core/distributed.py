@@ -92,12 +92,10 @@ def build_bootstrap_step(mesh: Mesh, stat: Statistic, B: int,
         estimate = stat.finalize(est_state)
         return thetas, estimate
 
-    from repro.compat import shard_map_compat
-    shard_map, sm_kw = shard_map_compat()
     in_specs = (P(data_axes, None), P(data_axes), P())
     out_specs = (P(), P())
-    fn = shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
-                   out_specs=out_specs, **sm_kw)
+    fn = jax.shard_map(shard_fn, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return jax.jit(fn, donate_argnums=(0, 1) if donate else ())
 
 

@@ -32,5 +32,4 @@ hot spots are:
 
 Each subpackage: kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd
 wrapper w/ padding + platform dispatch), ref.py (pure-jnp oracle).
-Kernels are validated on CPU with interpret=True against ref.py.
 """

@@ -51,7 +51,8 @@ def _fm_kernel(scal_ref, x_ref, *refs, kinds, hist_nbins, hist_out_bins,
     out_refs = refs[2 * n_hist + (1 if has_mask else 0):]
 
     # ONE weight tile for every slot below — the whole point of the kernel.
-    w = _poisson_tile(scal_ref[0], i, t, (block_b, block_n), scal_ref[1],
+    w = _poisson_tile(scal_ref[0], i, t, pl.num_programs(1),
+                      (block_b, block_n), scal_ref[1],
                       block_n, use_tpu_prng,
                       valid=None if m_ref is None else m_ref[...])  # (bB, bn)
     x = x_ref[...].astype(jnp.float32)                        # (bn, dp)

@@ -140,7 +140,8 @@ def _fpk_kernel(scal_ref, x_ref, c_ref, *refs,
     i = pl.program_id(0)        # B-tile index
     t = pl.program_id(1)        # n-tile index (contraction)
 
-    w = _poisson_tile(scal_ref[0], i, t, (block_b, block_n), scal_ref[1],
+    w = _poisson_tile(scal_ref[0], i, t, pl.num_programs(1),
+                      (block_b, block_n), scal_ref[1],
                       block_n, use_tpu_prng,
                       valid=None if m_ref is None else m_ref[...])  # (bB, bn)
     x = x_ref[...].astype(jnp.float32)                       # (bn, dp)

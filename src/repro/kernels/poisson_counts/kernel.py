@@ -11,6 +11,9 @@ Seeding is per-tile: (seed, tile_i, tile_j) fully determines a tile, so a
 fixed (seed, block config) reproduces the same matrix call-to-call, and
 different shards/steps decorrelate by folding their id into ``seed``
 before the call (as core/distributed.py does at the jax.random level).
+The TPU PRNG takes at most two seed words, so the hardware path seeds with
+(seed, tile_i·n_tiles + tile_j), with n_tiles the call's static tile count
+on the n axis — still one stream per (seed, tile) within a call.
 """
 from __future__ import annotations
 
@@ -37,8 +40,19 @@ def _threefry_bits(seed, i, j, shape):
     return jax.random.bits(key, shape, dtype=jnp.uint32)
 
 
+def _tpu_prng_bits(seed, i, j, n_tiles, shape):
+    """Hardware-PRNG bits for tile (i, j) of a grid with ``n_tiles`` tiles
+    on the j axis.  Mosaic seeds from at most two words, so the tile enters
+    as the single index i·n_tiles + j, injective within a call."""
+    pltpu.prng_seed(seed, i * n_tiles + j)
+    return pltpu.bitcast(pltpu.prng_random_bits(shape), jnp.uint32)
+
+
 def _poisson_from_bits(bits: jax.Array) -> jax.Array:
-    u = (bits >> jnp.uint32(8)).astype(jnp.float32) * jnp.float32(1.0 / (1 << 24))
+    # the shifted bits are < 2^24, so going through int32 (Mosaic has no
+    # uint32 -> f32 cast) is exact and leaves the threefry path unchanged.
+    u = ((bits >> jnp.uint32(8)).astype(jnp.int32).astype(jnp.float32)
+         * jnp.float32(1.0 / (1 << 24)))
     counts = jnp.zeros(bits.shape, jnp.float32)
     for c in _CDF:
         counts += (u > jnp.float32(c)).astype(jnp.float32)
@@ -49,9 +63,8 @@ def _pc_kernel(seed_ref, out_ref, *, use_tpu_prng: bool):
     i = pl.program_id(0)
     j = pl.program_id(1)
     if use_tpu_prng:
-        pltpu.prng_seed(seed_ref[0], i, j)
-        bits = pltpu.prng_random_bits(out_ref.shape)
-        bits = pltpu.bitcast(bits, jnp.uint32)
+        bits = _tpu_prng_bits(seed_ref[0], i, j, pl.num_programs(1),
+                              out_ref.shape)
     else:
         bits = _threefry_bits(seed_ref[0], i, j, out_ref.shape)
     out_ref[...] = _poisson_from_bits(bits)

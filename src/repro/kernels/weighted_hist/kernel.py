@@ -116,7 +116,8 @@ def _fph_kernel(scal_ref, x_ref, lo_ref, hi_ref, *refs, nbins: int,
     i = pl.program_id(0)        # B-tile index
     t = pl.program_id(1)        # n-tile index (contraction)
 
-    w = _poisson_tile(scal_ref[0], i, t, (block_b, block_n), scal_ref[1],
+    w = _poisson_tile(scal_ref[0], i, t, pl.num_programs(1),
+                      (block_b, block_n), scal_ref[1],
                       block_n, use_tpu_prng,
                       valid=None if m_ref is None else m_ref[...])  # (bB, bn)
     x = x_ref[...].astype(jnp.float32)                       # (bn, dp)
@@ -155,9 +156,11 @@ def _fph_binblocked_kernel(scal_ref, xt_ref, lo_ref, hi_ref, *refs,
     The weight tile is keyed by (seed, i, t) only — regenerating it per
     (c, j) cell trades PRNG recompute for VMEM residency, and keeps the
     implicit weight matrix bit-identical to every other fused path.  x
-    arrives TRANSPOSED as (dp, n) so the value row for dimension c is
-    selected by the BlockSpec (no traced lane slicing in-kernel); lo/hi
-    arrive as (dp, 1) blocks selected the same way.
+    arrives TRANSPOSED as (dp, 1, n) so the value row for dimension c is
+    selected by the BlockSpec's squeezed leading axis (no traced lane
+    slicing in-kernel, and the (1, block_n) block spans the full second-
+    minor axis, as the TPU's (8, 128) tiling rule requires for any d);
+    lo/hi arrive as (dp, 1, 1) and are selected the same way.
     """
     if has_mask:
         m_ref, out_ref = refs
@@ -168,7 +171,8 @@ def _fph_binblocked_kernel(scal_ref, xt_ref, lo_ref, hi_ref, *refs,
     t = pl.program_id(2)        # n-tile index (contraction)
     j = cj % nb_j               # bin-block within the dimension
 
-    w = _poisson_tile(scal_ref[0], i, t, (block_b, block_n), scal_ref[1],
+    w = _poisson_tile(scal_ref[0], i, t, pl.num_programs(2),
+                      (block_b, block_n), scal_ref[1],
                       block_n, use_tpu_prng,
                       valid=None if m_ref is None else m_ref[...])  # (bB, bn)
     x = xt_ref[...].astype(jnp.float32)                       # (1, bn)
@@ -203,8 +207,8 @@ def fused_poisson_hist_binblocked_kernel(seed: jax.Array, n_valid: jax.Array,
                                          mask=None) -> jax.Array:
     """Raw entry for the output-tiled fused hist kernel.
 
-    values_t is the TRANSPOSED (dp, n) value matrix (n pre-padded to
-    block_n, dp the lane-padded dimension count); lo/hi are (dp, 1).
+    values_t is the TRANSPOSED (dp, 1, n) value matrix (n pre-padded to
+    block_n, dp >= d_valid); lo/hi are (dp, 1, 1).
     ``block_bins`` (a 128 multiple) is the per-instance output window —
     out_bins = nbins padded up to a block_bins multiple, nb_j = out_bins /
     block_bins output blocks per dimension (>= 2 is the interesting
@@ -212,7 +216,7 @@ def fused_poisson_hist_binblocked_kernel(seed: jax.Array, n_valid: jax.Array,
     (B, d_valid, out_bins) and slice [..., :nbins] (bins past the true
     nbins stay empty: binning is against the true nbins).
     """
-    dp, n = values_t.shape
+    dp, _, n = values_t.shape
     assert B % block_b == 0 and n % block_n == 0, ((B, n), (block_b, block_n))
     assert block_bins % 128 == 0 and block_bins > 0, block_bins
     assert d_valid <= dp, (d_valid, dp)
@@ -228,9 +232,10 @@ def fused_poisson_hist_binblocked_kernel(seed: jax.Array, n_valid: jax.Array,
     grid = (B // block_b, d_valid * nb_j, n // block_n)
     in_specs = [
         pl.BlockSpec(memory_space=pltpu.SMEM),
-        pl.BlockSpec((1, block_n), lambda i, cj, t: (cj // nb_j, t)),
-        pl.BlockSpec((1, 1), lambda i, cj, t: (cj // nb_j, 0)),
-        pl.BlockSpec((1, 1), lambda i, cj, t: (cj // nb_j, 0)),
+        pl.BlockSpec((None, 1, block_n),
+                     lambda i, cj, t: (cj // nb_j, 0, t)),
+        pl.BlockSpec((None, 1, 1), lambda i, cj, t: (cj // nb_j, 0, 0)),
+        pl.BlockSpec((None, 1, 1), lambda i, cj, t: (cj // nb_j, 0, 0)),
     ]
     operands = [scal, values_t, lo, hi]
     if mask is not None:

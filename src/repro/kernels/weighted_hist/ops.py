@@ -241,7 +241,8 @@ def fused_poisson_hist(seed, values: jax.Array, lo, hi, nbins: int, B: int,
         # output-tiled variant: x transposed so the BlockSpec (not a traced
         # lane slice) selects each dimension's value row.
         counts = fused_poisson_hist_binblocked_kernel(
-            seed, n_valid, xp.T, lo[:, None], hi[:, None], Bp, nbins,
+            seed, n_valid, xp.T[:, None, :], lo[:, None, None],
+            hi[:, None, None], Bp, nbins,
             d_valid=d, block_bins=block_bins, block_b=bb, block_n=bn,
             interpret=(backend != "pallas"),
             use_tpu_prng=(backend == "pallas"), mask=mp2)

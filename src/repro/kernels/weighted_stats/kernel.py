@@ -34,7 +34,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.poisson_counts.kernel import (_poisson_from_bits,
-                                                 _threefry_bits)
+                                                 _threefry_bits,
+                                                 _tpu_prng_bits)
 
 
 def _ws_kernel(w_ref, x_ref, wtot_ref, s1_ref, s2_ref):
@@ -103,13 +104,15 @@ def weighted_moments_kernel(weights: jax.Array, values: jax.Array,
 # ============================================================================
 # matrix-free path: in-kernel weight generation + contraction
 # ============================================================================
-def _poisson_tile(seed, i, k, shape, n_valid, block_n: int,
+def _poisson_tile(seed, i, k, n_tiles, shape, n_valid, block_n: int,
                   use_tpu_prng: bool, valid=None) -> jax.Array:
     """Poisson(1) weight tile for grid position (i, k), padding masked to 0.
 
     Identical per-tile seeding to kernels/poisson_counts (same fold-in order,
     same CDF ladder), so the implicit weight matrix equals
     ``poisson_counts(seed, B, n)`` under matching block shapes.
+    ``n_tiles`` (the call's tile count on the n axis) only enters the
+    hardware-PRNG seed, see ``_tpu_prng_bits``.
 
     ``valid`` (optional (1, block_n) f32 of exact 0.0/1.0) is this tile's
     slice of an arbitrary validity mask, multiplied in AFTER the prefix
@@ -118,8 +121,7 @@ def _poisson_tile(seed, i, k, shape, n_valid, block_n: int,
     mask reproduces the ``n_valid`` path bit for bit).
     """
     if use_tpu_prng:
-        pltpu.prng_seed(seed, i, k)
-        bits = pltpu.bitcast(pltpu.prng_random_bits(shape), jnp.uint32)
+        bits = _tpu_prng_bits(seed, i, k, n_tiles, shape)
     else:
         bits = _threefry_bits(seed, i, k, shape)
     w = _poisson_from_bits(bits)
@@ -140,7 +142,8 @@ def _fpm_kernel(scal_ref, x_ref, *refs, block_b: int, block_n: int,
     j = pl.program_id(1)        # d-tile index
     k = pl.program_id(2)        # n-tile index (contraction)
 
-    w = _poisson_tile(scal_ref[0], i, k, (block_b, block_n), scal_ref[1],
+    w = _poisson_tile(scal_ref[0], i, k, pl.num_programs(2),
+                      (block_b, block_n), scal_ref[1],
                       block_n, use_tpu_prng,
                       valid=None if m_ref is None else m_ref[...])
     x = x_ref[...].astype(jnp.float32)       # (bn, bd)
@@ -244,7 +247,8 @@ def _fpm_grouped_kernel(scal_ref, x_ref, g_ref, *refs, block_b: int,
     i = pl.program_id(0)        # B-tile index
     k = pl.program_id(1)        # n-tile index (contraction)
 
-    w = _poisson_tile(scal_ref[0], i, k, (block_b, block_n), scal_ref[1],
+    w = _poisson_tile(scal_ref[0], i, k, pl.num_programs(1),
+                      (block_b, block_n), scal_ref[1],
                       block_n, use_tpu_prng,
                       valid=None if m_ref is None else m_ref[...])
     gid = g_ref[...]                         # (1, block_n) f32 keys
@@ -387,7 +391,7 @@ def _fpm_stream_kernel(scal_ref, x_hbm_ref, *refs, block_b: int,
         if has_mask:
             m_dma(slot, t).wait()
             valid = ms[slot]
-        w = _poisson_tile(scal_ref[0], i, t, (block_b, block_n),
+        w = _poisson_tile(scal_ref[0], i, t, nt, (block_b, block_n),
                           scal_ref[1], block_n, use_tpu_prng, valid=valid)
         x = xs[slot].astype(jnp.float32)
         s1_ref[...] += jax.lax.dot(w.astype(dtype), x.astype(dtype),
@@ -430,12 +434,12 @@ def fused_poisson_moments_stream_kernel(seed: jax.Array, n_valid: jax.Array,
     scal = jnp.stack([jnp.asarray(seed, jnp.int32),
                       jnp.asarray(n_valid, jnp.int32)])
     in_specs = [pl.BlockSpec(memory_space=pltpu.SMEM),
-                pl.BlockSpec(memory_space=pltpu.ANY)]
+                pl.BlockSpec(memory_space=pl.ANY)]
     operands = [scal, values]
     scratch = [pltpu.VMEM((2, block_n, d), jnp.float32),
                pltpu.SemaphoreType.DMA((2,))]
     if mask is not None:
-        in_specs.append(pl.BlockSpec(memory_space=pltpu.ANY))
+        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))
         operands.append(mask)
         scratch += [pltpu.VMEM((2, 1, block_n), jnp.float32),
                     pltpu.SemaphoreType.DMA((2,))]

@@ -339,7 +339,11 @@ def implicit_weights(seed, B: int, n: int, block_b: int = 128,
     Used as the test oracle and as the fused_rng fallback for statistics
     without a moment decomposition.  Note: on TPU the compiled kernel draws
     its bits from the hardware PRNG (``use_tpu_prng=True``), which is
-    distributionally identical but NOT bit-identical to this matrix.
+    distributionally identical but NOT bit-identical to this matrix.  The
+    hardware PRNG takes two seed words, so a tile is seeded with (seed,
+    b_tile * n_tiles + n_tile), n_tiles being the call's static tile count
+    on the n axis: on the chip too, (seed, tile) fully determines a tile,
+    and every kernel family draws the same tile for the same (seed, B, n).
     """
     bb, bn = weight_tile_blocks(B, n, block_b, block_n)
     nb_b = (B + (-B) % bb) // bb

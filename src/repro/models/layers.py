@@ -367,9 +367,6 @@ def moe_ffn_shard_map(cfg: ModelConfig, p: Params, x: jax.Array
 
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map_compat
-    shard_map, sm_kw = shard_map_compat()
-
     def body(p_loc, x_loc):
         y = _moe_route_compute(cfg, p_loc, x_loc)
         if cfg.dense_residual:
@@ -390,8 +387,8 @@ def moe_ffn_shard_map(cfg: ModelConfig, p: Params, x: jax.Array
             "w_down": P(model_axes, None),
         }
     x_spec = P(batch_axes if batch_axes else None, None, None)
-    fn = shard_map(body, mesh=mesh, in_specs=(p_specs, x_spec),
-                   out_specs=x_spec, **sm_kw)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(p_specs, x_spec),
+                       out_specs=x_spec, check_vma=False)
     return fn({k: p[k] for k in p_specs}, x)
 
 

@@ -22,3 +22,16 @@ def key():
 @pytest.fixture
 def rng():
     return np.random.default_rng(0)
+
+
+@pytest.fixture(scope="session")
+def lane_padded():
+    """Zero-pad the columns of x to the 128-lane width the ops layer hands
+    every Pallas kernel.  XLA:CPU picks its dot kernel, and so its f32
+    reduction order, by shape: a scan lowering fed the padded x contracts
+    the same shapes as the kernel and must match it bit for bit, where the
+    unpadded scan may differ from it in the last bits."""
+    def pad(x):
+        x = jax.numpy.asarray(x)
+        return jax.numpy.pad(x, ((0, 0), (0, 128 - x.shape[1])))
+    return pad

@@ -294,7 +294,10 @@ class TestSessionKillResume:
     rounds resumes from its checkpointed delta-maintained carry and ends
     with the identical early result."""
 
-    SIGMA = 0.01
+    # at sigma=0.01 the pilot's fitted c_v floor (~0.009) sits at sigma, so
+    # SSABE asks for the whole store and the session rightly runs the exact
+    # job instead; 0.02 needs two expansion rounds, which the kill needs.
+    SIGMA = 0.02
 
     def _session(self, store, checkpoint=None):
         return EarlSession(PreMapSampler(store, seed=4), Mean(),

@@ -115,15 +115,17 @@ class TestPrefixMaskBitwiseEquivalence:
 
 class TestKernelScanParity:
     """Masked Pallas kernels ≡ masked scan lowerings, bitwise (same
-    shared tile math on both sides)."""
+    shared tile math on both sides).  Where the kernel contracts a dot, the
+    scan is fed the kernel's lane-padded x (``lane_padded``) so both sum
+    in the same order."""
 
-    def test_moments(self, x2, interior_mask):
+    def test_moments(self, x2, interior_mask, lane_padded):
         from repro.kernels.weighted_stats.ops import fused_poisson_moments
-        s = fused_poisson_moments(SEED, x2, B, valid_mask=interior_mask,
-                                  backend="scan")
+        s = fused_poisson_moments(SEED, lane_padded(x2), B,
+                                  valid_mask=interior_mask, backend="scan")
         k = fused_poisson_moments(SEED, x2, B, valid_mask=interior_mask,
                                   backend="pallas_interpret")
-        _tree_bitwise(s, k)
+        _tree_bitwise((s[0], s[1][:, :D], s[2][:, :D]), k)
 
     def test_moments_stream_kernel(self, x2, interior_mask):
         """The DMA double-buffered n-loop kernel produces the same bits as
@@ -149,30 +151,32 @@ class TestKernelScanParity:
         _tree_bitwise(s, k)
         _tree_bitwise(s, bb)
 
-    def test_kmeans(self, x2, interior_mask):
+    def test_kmeans(self, x2, interior_mask, lane_padded):
         from repro.kernels.kmeans_assign.ops import fused_poisson_kmeans
         cent = jnp.asarray(np.random.default_rng(3)
                            .normal(size=(5, D)).astype(np.float32))
-        s = fused_poisson_kmeans(SEED, x2, cent, B, backend="scan",
-                                 valid_mask=interior_mask)
+        s = fused_poisson_kmeans(SEED, lane_padded(x2), lane_padded(cent),
+                                 B, backend="scan", valid_mask=interior_mask)
         k = fused_poisson_kmeans(SEED, x2, cent, B,
                                  backend="pallas_interpret",
                                  valid_mask=interior_mask)
         # sums/counts are bitwise (integer-weighted dot sums); inertia's
         # matvec-vs-dot reduction differs by ulps between the lowerings
         # (pre-existing, mask-independent) — allclose there.
-        _tree_bitwise(s[:2], k[:2])
+        _tree_bitwise((s[0][..., :D], s[1]), k[:2])
         _tree_allclose(s[2], k[2], rtol=1e-5)
 
-    def test_multi(self, x2, interior_mask):
+    def test_multi(self, x2, interior_mask, lane_padded):
         from repro.kernels.fused_multi.ops import fused_poisson_multi
         g = StatisticGroup([Mean(),
                             Quantile(0.5, lo=-4.0, hi=4.0, nbins=33)])
-        s = fused_poisson_multi(g, SEED, x2, B, backend="scan",
+        s = fused_poisson_multi(g, SEED, lane_padded(x2), B, backend="scan",
                                 valid_mask=interior_mask)
         k = fused_poisson_multi(g, SEED, x2, B, backend="pallas_interpret",
                                 valid_mask=interior_mask)
-        _tree_bitwise(s, k)
+        sm, sh = s
+        _tree_bitwise((sm.w, sm.s1[:, :D], sm.s2[:, :D], sh.counts[:, :D],
+                       sh.lo[:, :D], sh.hi[:, :D]), tuple(jax.tree.leaves(k)))
 
 
 class _NoFusedPath(Statistic):

@@ -137,14 +137,14 @@ class TestFusedMoments:
             b = pc_ops.poisson_counts(13, B, n, backend="pallas_interpret")
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
-    def test_scan_equals_interpret(self, key):
+    def test_scan_equals_interpret(self, key, lane_padded):
         x = jax.random.normal(key, (900, 4))
-        a = ws_ops.fused_poisson_moments(9, x, 48, backend="scan")
+        a = ws_ops.fused_poisson_moments(9, lane_padded(x), 48,
+                                         backend="scan")
         b = ws_ops.fused_poisson_moments(9, x, 48,
                                          backend="pallas_interpret")
-        for u, v in zip(a, b):
-            np.testing.assert_allclose(np.asarray(u), np.asarray(v),
-                                       rtol=1e-6)
+        for u, v in zip((a[0], a[1][:, :4], a[2][:, :4]), b):
+            np.testing.assert_array_equal(np.asarray(u), np.asarray(v))
 
     def test_deterministic_and_seed_sensitive(self, key):
         x = jax.random.normal(key, (512,))
