@@ -610,7 +610,8 @@ def run_stream(smoke: bool = False) -> None:
          f"B={B};chunk={chunk};nchunks={nchunks};d={d};queue_depth=2;"
          f"stage_us={rs.stream.stage_s * 1e6:.0f};"
          f"wait_us={rs.stream.wait_s * 1e6:.0f};"
-         f"dispatch_us={rs.stream.dispatch_s * 1e6:.0f}")
+         f"dispatch_us={rs.stream.dispatch_s * 1e6:.0f};"
+         f"drain_us={rs.stream.drain_s * 1e6:.0f}")
     emit("stream_serial_baseline", us_serial,
          f"stream_speedup={speedup:.2f}x;transfer_us={us_xfer:.0f};"
          f"compute_us={us_comp:.0f};overlap_eff={overlap_eff:.2f}")
@@ -634,6 +635,7 @@ def run_stream(smoke: bool = False) -> None:
         "stream_report": {"stage_s": rs.stream.stage_s,
                           "wait_s": rs.stream.wait_s,
                           "dispatch_s": rs.stream.dispatch_s,
+                          "drain_s": rs.stream.drain_s,
                           "n_chunks": rs.stream.n_chunks,
                           "rows": rs.stream.rows},
     }, indent=2) + "\n")

@@ -302,6 +302,7 @@ def phase_stream(rec, store, exact, B=256):
         facts.update(estimate=float(np.ravel(res.estimate)[0]),
                      exact=float(exact[0]), stage_s=rep.stage_s,
                      wait_s=rep.wait_s, dispatch_s=rep.dispatch_s,
+                     drain_s=rep.drain_s,
                      n_chunks=rep.n_chunks)
         _check(rep.rows == store.N and res.n == store.N,
                f"stream folded {res.n} of {store.N} rows")

@@ -14,6 +14,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import trace
+
 _EPS = 1e-12
 
 
@@ -77,9 +79,9 @@ class AccuracyReport:
     def from_thetas(thetas: jax.Array, alpha: float = 0.05) -> "AccuracyReport":
         lo, hi = percentile_ci(thetas, alpha)
         return AccuracyReport(
-            cv=float(coefficient_of_variation(thetas)),
-            se=float(standard_error(thetas)),
-            rel_halfwidth=float(relative_halfwidth(thetas)),
+            cv=trace.to_host(coefficient_of_variation(thetas), float),
+            se=trace.to_host(standard_error(thetas), float),
+            rel_halfwidth=trace.to_host(relative_halfwidth(thetas), float),
             ci_lo=lo,
             ci_hi=hi,
             boot_mean=jnp.mean(_flatten_thetas(thetas), axis=0),
@@ -188,4 +190,4 @@ def theoretical_sample_size(sigma: float, pilot_std: float, pilot_mean: float
     shows SSABE's empirical estimate beats this in both directions.
     """
     rel = pilot_std / (abs(pilot_mean) + _EPS)
-    return max(1, int(jnp.ceil((rel / sigma) ** 2)))
+    return max(1, trace.to_host(jnp.ceil((rel / sigma) ** 2), int))

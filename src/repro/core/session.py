@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import trace
 from repro.core import ssabe as ssabe_mod
 from repro.core.bootstrap import BootstrapResult, seed_from_key
 from repro.core.delta import (PoissonDelta, poisson_delta_extend,
@@ -120,34 +121,43 @@ class EarlSession:
 
     # ------------------------------------------------------------------ #
     def _full_job(self, t0: float, history) -> EarlyResult:
-        N = self.sampler.N
-        values = self.sampler.take(0, N)
-        res = self.stat(values)
-        # groups always get per-member reports, even on the exact-job
-        # fallback (degenerate: cv 0, CI collapsed onto the exact answer),
-        # so consumers can iterate EarlyResult.reports unconditionally.
-        reports = None
-        if isinstance(res, tuple):
-            from repro.core.accuracy import AccuracyReport
-            reports = tuple(
-                AccuracyReport(cv=0.0, se=0.0, rel_halfwidth=0.0,
-                               ci_lo=r, ci_hi=r, boot_mean=r)
-                for r in res)
-        elif getattr(self.stat, "num_groups", None) is not None:
-            # keyed runs get the same guarantee per KEY: a GroupedStatistic
-            # result is a (G, ...) array, one degenerate report per key.
-            from repro.core.accuracy import AccuracyReport
-            reports = tuple(
-                AccuracyReport(cv=0.0, se=0.0, rel_halfwidth=0.0,
-                               ci_lo=res[g], ci_hi=res[g], boot_mean=res[g])
-                for g in range(int(self.stat.num_groups)))
-        return EarlyResult(
-            result=res, cv=0.0, ci_lo=res, ci_hi=res, n_used=N, N=N,
-            fraction=1.0, B=1, iterations=len(history), fell_back=True,
-            history=history, wall_time_s=time.perf_counter() - t0,
-            ssabe=None, reports=reports)
+        with trace.span("session.full_job"):
+            N = self.sampler.N
+            values = self.sampler.take(0, N)
+            res = self.stat(values)
+            # groups always get per-member reports, even on the exact-job
+            # fallback (degenerate: cv 0, CI collapsed onto the exact
+            # answer), so consumers can iterate EarlyResult.reports
+            # unconditionally.
+            reports = None
+            if isinstance(res, tuple):
+                from repro.core.accuracy import AccuracyReport
+                reports = tuple(
+                    AccuracyReport(cv=0.0, se=0.0, rel_halfwidth=0.0,
+                                   ci_lo=r, ci_hi=r, boot_mean=r)
+                    for r in res)
+            elif getattr(self.stat, "num_groups", None) is not None:
+                # keyed runs get the same guarantee per KEY: a
+                # GroupedStatistic result is a (G, ...) array, one
+                # degenerate report per key.
+                from repro.core.accuracy import AccuracyReport
+                reports = tuple(
+                    AccuracyReport(cv=0.0, se=0.0, rel_halfwidth=0.0,
+                                   ci_lo=res[g], ci_hi=res[g],
+                                   boot_mean=res[g])
+                    for g in range(int(self.stat.num_groups)))
+            return EarlyResult(
+                result=res, cv=0.0, ci_lo=res, ci_hi=res, n_used=N, N=N,
+                fraction=1.0, B=1, iterations=len(history), fell_back=True,
+                history=history, wall_time_s=time.perf_counter() - t0,
+                ssabe=None, reports=reports)
 
     def run(self, key: jax.Array, resume: bool = False) -> EarlyResult:
+        trace.count("session_run")
+        with trace.span("session.run"):
+            return self._run(key, resume)
+
+    def _run(self, key: jax.Array, resume: bool) -> EarlyResult:
         t0 = time.perf_counter()
         N = self.sampler.N
         history: List[dict] = []
@@ -163,7 +173,8 @@ class EarlSession:
         # ---- pilot + SSABE (local mode) --------------------------------
         n_pilot = min(N, self.max_pilot,
                       max(self.min_pilot, int(self.p_pilot * N)))
-        pilot = self.sampler.take(0, n_pilot)
+        with trace.span("session.pilot"):
+            pilot = self.sampler.take(0, n_pilot)
         est = ssabe_mod.ssabe(pilot, self.stat, self.sigma, self.tau,
                               jax.random.fold_in(key, 1), l=self.l, N=N,
                               backend=self.backend, mesh=self.mesh,
@@ -182,7 +193,8 @@ class EarlSession:
                                 data_axis=self.data_axis)
         spec, params = split_params(self.stat)
         fp = run_fingerprint(spec, params, int(B),
-                             int(seed_from_key(pd.key)), N, dim)
+                             trace.to_host(seed_from_key(pd.key), int), N,
+                             dim)
         n_have = 0
         iterations = 0
         if resume:
@@ -216,7 +228,9 @@ class EarlSession:
             # between the save and the return): re-derive the result from
             # the restored carry and re-check before extending further.
             p = n_have / N
-            res = poisson_delta_result(pd, p=p, p_keys=self._p_keys(n_have))
+            with trace.span("session.result"):
+                res = poisson_delta_result(pd, p=p,
+                                           p_keys=self._p_keys(n_have))
             if res.cv <= self.sigma or n_have >= self.max_fraction * N:
                 return EarlyResult(
                     result=res.estimate, cv=res.cv,
@@ -228,40 +242,48 @@ class EarlSession:
                     reports=getattr(res.report, "members", None))
         while True:
             iterations += 1
-            n_goal = min(int(n_target), N)
-            delta = self.sampler.take(n_have, n_goal)
-            pd = poisson_delta_extend(pd, delta)
-            n_have = n_goal
-            p = n_have / N
-            # the point estimate is delta-maintained in pd.est_state (each
-            # extend folds Δs in, O(Δn)); recomputing stat(take(0, n_have))
-            # here would re-read the whole prefix every round, O(n).
-            res: BootstrapResult = poisson_delta_result(
-                pd, p=p, p_keys=self._p_keys(n_have))
-            # for a StatisticGroup, res.cv is the WORST member's c_v
-            # (GroupAccuracyReport), so the sigma gate below stops only
-            # when ALL members meet the target; the per-member trace is
-            # recorded so sessions can see who the straggler was.
-            entry = dict(iteration=iterations, n=n_have, B=int(B),
-                         cv=float(res.cv), t=time.perf_counter() - t0)
-            member_reports = getattr(res.report, "members", None)
-            if member_reports is not None:
-                entry["member_cvs"] = tuple(float(r.cv)
-                                            for r in member_reports)
-            history.append(entry)
-            if mgr is not None and iterations % self.checkpoint_every == 0:
-                # the cursor rides meta.json, so history must be JSON-plain
-                mgr.save(iterations, (pd.states, pd.est_state),
-                         extra={"cursor": dict(
-                             kind="session", fingerprint=fp,
-                             n_have=int(n_have), step=int(pd.step),
-                             iterations=int(iterations),
-                             n_target_next=int(min(
-                                 N, int(n_have * self.growth))),
-                             history=[
-                                 {**e, "member_cvs": list(e["member_cvs"])}
-                                 if "member_cvs" in e else e
-                                 for e in history])})
+            with trace.span("session.round", round=iterations):
+                n_goal = min(int(n_target), N)
+                delta = self.sampler.take(n_have, n_goal)
+                with trace.span("delta.extend"):
+                    pd = poisson_delta_extend(pd, delta)
+                n_have = n_goal
+                p = n_have / N
+                # the point estimate is delta-maintained in pd.est_state
+                # (each extend folds Δs in, O(Δn)); recomputing
+                # stat(take(0, n_have)) here would re-read the whole prefix
+                # every round, O(n).
+                with trace.span("session.result"):
+                    res: BootstrapResult = poisson_delta_result(
+                        pd, p=p, p_keys=self._p_keys(n_have))
+                # for a StatisticGroup, res.cv is the WORST member's c_v
+                # (GroupAccuracyReport), so the sigma gate below stops
+                # only when ALL members meet the target; the per-member
+                # trace is recorded so sessions can see who the straggler
+                # was.
+                entry = dict(iteration=iterations, n=n_have, B=int(B),
+                             cv=float(res.cv), t=time.perf_counter() - t0)
+                member_reports = getattr(res.report, "members", None)
+                if member_reports is not None:
+                    entry["member_cvs"] = tuple(float(r.cv)
+                                                for r in member_reports)
+                history.append(entry)
+                if (mgr is not None
+                        and iterations % self.checkpoint_every == 0):
+                    # the cursor rides meta.json, so history must be
+                    # JSON-plain
+                    mgr.save(iterations, (pd.states, pd.est_state),
+                             extra={"cursor": dict(
+                                 kind="session", fingerprint=fp,
+                                 n_have=int(n_have), step=int(pd.step),
+                                 iterations=int(iterations),
+                                 n_target_next=int(min(
+                                     N, int(n_have * self.growth))),
+                                 history=[
+                                     {**e,
+                                      "member_cvs": list(e["member_cvs"])}
+                                     if "member_cvs" in e else e
+                                     for e in history])})
             if res.cv <= self.sigma or n_have >= self.max_fraction * N:
                 if mgr is not None:
                     mgr.wait()          # durable before we report success

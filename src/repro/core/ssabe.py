@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import trace
 from repro.core import accuracy
 from repro.core.bootstrap import (bootstrap_thetas, fused_resample_states,
                                   seed_from_key, sharded_fused_states,
@@ -38,13 +39,13 @@ def _cv_of(thetas, num_groups=None) -> float:
     once every member of the group is stable (the group's AES contract).
     With ``num_groups`` (a GroupedStatistic's (B, G, ...) thetas) it is the
     WORST KEY, the per-key analogue of the same contract."""
+    def cv(t):
+        return trace.to_host(accuracy.coefficient_of_variation(t), float)
     if isinstance(thetas, (tuple, list)):
-        return max(float(accuracy.coefficient_of_variation(t))
-                   for t in thetas)
+        return max(cv(t) for t in thetas)
     if num_groups is not None:
-        return max(float(accuracy.coefficient_of_variation(thetas[:, g]))
-                   for g in range(int(num_groups)))
-    return float(accuracy.coefficient_of_variation(thetas))
+        return max(cv(thetas[:, g]) for g in range(int(num_groups)))
+    return cv(thetas)
 
 
 @dataclasses.dataclass
@@ -191,17 +192,20 @@ def ssabe(pilot_values: jax.Array, stat: Statistic, sigma: float, tau: float,
     move)."""
     acc = accuracy
     kb, kn = jax.random.split(jax.random.fold_in(key, 0xEA))
-    B_hat, hist_B = estimate_B(pilot_values, stat, tau, kb, engine=engine,
-                               backend=backend, mesh=mesh,
-                               data_axis=data_axis)
-    n_cap = N if N is not None else int(1e12)
-    n_hat, hist_n, a, c = estimate_n(pilot_values, stat, sigma, B_hat, kn,
-                                     l=l, n_cap=n_cap, backend=backend,
-                                     mesh=mesh, data_axis=data_axis)
+    with trace.span("ssabe"):
+        with trace.span("ssabe.B"):
+            B_hat, hist_B = estimate_B(pilot_values, stat, tau, kb,
+                                       engine=engine, backend=backend,
+                                       mesh=mesh, data_axis=data_axis)
+        n_cap = N if N is not None else int(1e12)
+        with trace.span("ssabe.n"):
+            n_hat, hist_n, a, c = estimate_n(
+                pilot_values, stat, sigma, B_hat, kn, l=l, n_cap=n_cap,
+                backend=backend, mesh=mesh, data_axis=data_axis)
 
-    x = np.asarray(_as_2d(pilot_values))
-    n_theory = acc.theoretical_sample_size(
-        sigma, float(x.std()), float(x.mean()))
+        x = trace.to_host(_as_2d(pilot_values))
+        n_theory = acc.theoretical_sample_size(
+            sigma, float(x.std()), float(x.mean()))
     return SSABEResult(
         B=B_hat, n=n_hat,
         cv_history_B=hist_B, cv_history_n=hist_n,

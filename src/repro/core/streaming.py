@@ -53,6 +53,7 @@ Crash safety (three orthogonal mechanisms, all off by default):
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import hashlib
 import queue as queue_mod
@@ -66,6 +67,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import trace
 from repro.core import accuracy
 from repro.core.bootstrap import (BootstrapResult, fused_resample_states,
                                   offset_seed, seed_from_key)
@@ -79,10 +81,13 @@ class StreamReport:
     ``stage_s`` is the prefetch thread's cumulative batch-preparation +
     ``device_put`` enqueue time; ``wait_s`` is how long the main thread
     sat idle on the hand-off queue (transfer-bound when large);
-    ``dispatch_s`` is the main thread's per-chunk dispatch time plus the
-    single trailing-edge ``block_until_ready`` (compute-bound when
-    large).  Perfect overlap drives ``wall_s`` toward
-    max(stage, compute) instead of their sum.
+    ``dispatch_s`` is the main thread's time enqueueing the per-chunk
+    fold steps, and ``drain_s`` the single trailing-edge
+    ``block_until_ready`` after the last of them: the device work still
+    queued when the host is done (compute-bound when large).  Perfect
+    overlap drives ``wall_s`` toward max(stage, compute) instead of
+    their sum.  The same stretches appear in a profiler trace as the
+    ``earl.stream.*`` spans (``repro.trace``).
 
     Fault-tolerance accounting: ``checkpoint_s``/``n_checkpoints`` cost
     of the periodic snapshots, ``resumed_from_chunk`` where a resumed run
@@ -95,6 +100,7 @@ class StreamReport:
     stage_s: float
     wait_s: float
     dispatch_s: float
+    drain_s: float
     n_chunks: int
     rows: int
     checkpoint_s: float = 0.0
@@ -120,13 +126,19 @@ def _stream_chunk_jit(states, est, xi, vi, base_seed, i, params, spec, B):
     for the ragged tail (bit-identical to the historical n_valid path),
     with interior holes for rows of splits lost mid-run.  ``states``/
     ``est`` are donated so the carry is updated in place and the device
-    never holds two copies.
+    never holds two copies.  The point estimate's update and the resample
+    fold run under the name scopes ``earl.estimate`` and ``earl.resample``,
+    which name their operations in the compiled program's ``op_name``
+    metadata.
     """
     stat = bind_params(spec, params)
-    est = stat.update(est, xi, vi)
-    delta = fused_resample_states(stat, offset_seed(base_seed, i), xi, B,
-                                  valid_mask=vi)
-    return jax.vmap(stat.merge)(states, delta), est
+    with jax.named_scope("earl.estimate"):
+        est = stat.update(est, xi, vi)
+    with jax.named_scope("earl.resample"):
+        delta = fused_resample_states(stat, offset_seed(base_seed, i), xi,
+                                      B, valid_mask=vi)
+        states = jax.vmap(stat.merge)(states, delta)
+    return states, est
 
 
 def _put_until(out_q, item, stop) -> bool:
@@ -173,28 +185,30 @@ def _stage_batches(store, chunk: int, out_q, timings: dict, stop,
         for batch in store.iter_batches(chunk, start_row=start_row):
             if stop_row is not None and row0 >= stop_row:
                 break
-            t0 = time.perf_counter()
-            xb = np.asarray(batch, np.float32)
-            if xb.ndim == 1:
-                xb = xb[:, None]
-            if stop_row is not None and row0 + len(xb) > stop_row:
-                xb = xb[:stop_row - row0]
-            nb = len(xb)
-            mask = np.zeros((chunk,), np.float32)
-            mask[:nb] = 1.0
-            for lo, hi in (store.invalid_row_ranges()
-                           if hasattr(store, "invalid_row_ranges") else ()):
-                a, b = max(lo, row0) - row0, min(hi, row0 + nb) - row0
-                if a < b:
-                    mask[a:b] = 0.0
-            if nb < chunk:
-                xb = np.concatenate(
-                    [xb, np.zeros((chunk - nb,) + xb.shape[1:], xb.dtype)])
-            else:
-                xb = np.ascontiguousarray(xb)
-            xd = jax.device_put(xb)
-            md = jax.device_put(mask)
-            stage_s += time.perf_counter() - t0
+            with trace.span("stream.stage", chunk=row0 // chunk):
+                t0 = time.perf_counter()
+                xb = np.asarray(batch, np.float32)
+                if xb.ndim == 1:
+                    xb = xb[:, None]
+                if stop_row is not None and row0 + len(xb) > stop_row:
+                    xb = xb[:stop_row - row0]
+                nb = len(xb)
+                mask = np.zeros((chunk,), np.float32)
+                mask[:nb] = 1.0
+                for lo, hi in (store.invalid_row_ranges()
+                               if hasattr(store, "invalid_row_ranges")
+                               else ()):
+                    a, b = max(lo, row0) - row0, min(hi, row0 + nb) - row0
+                    if a < b:
+                        mask[a:b] = 0.0
+                if nb < chunk:
+                    pad = np.zeros((chunk - nb,) + xb.shape[1:], xb.dtype)
+                    xb = np.concatenate([xb, pad])
+                else:
+                    xb = np.ascontiguousarray(xb)
+                xd = jax.device_put(xb)
+                md = jax.device_put(mask)
+                stage_s += time.perf_counter() - t0
             row0 += nb
             if not _put_until(out_q, (xd, md, nb, int(mask.sum())), stop):
                 return
@@ -331,136 +345,148 @@ def bootstrap_streaming(store, stat: Statistic, B: int, key: jax.Array,
     if resume and checkpoint is None:
         raise ValueError("resume=True needs checkpoint= (where would the "
                          "cursor come from?)")
-    head = store.splits[0]
-    dim = int(np.prod(head.shape[1:])) if head.ndim > 1 else 1
+    # earl.stream.open: carry init, fingerprint, thread start and the
+    # first wait, up to the return of the first chunk's dispatch
+    opening = contextlib.ExitStack()
+    opening.enter_context(trace.span("stream.open"))
+    with opening:
+        head = store.splits[0]
+        dim = int(np.prod(head.shape[1:])) if head.ndim > 1 else 1
 
-    mgr = checkpoint
-    if isinstance(mgr, str):
-        from repro.checkpoint.manager import CheckpointManager
-        mgr = CheckpointManager(mgr, async_save=True)
+        mgr = checkpoint
+        if isinstance(mgr, str):
+            from repro.checkpoint.manager import CheckpointManager
+            mgr = CheckpointManager(mgr, async_save=True)
 
-    # resilient read path: policy wins over bare retry
-    reader = store
-    counters = None
-    if policy is not None or retry is not None:
-        from repro.ft.inject import FaultCounters, ResilientStore
-        counters = FaultCounters()
-        if policy is not None:
-            reader = ResilientStore(store, policy.retry, counters,
-                                    on_exhausted=policy.on_exhausted)
-        else:
-            reader = ResilientStore(store, retry, counters,
-                                    on_exhausted="raise")
+        # resilient read path: policy wins over bare retry
+        reader = store
+        counters = None
+        if policy is not None or retry is not None:
+            from repro.ft.inject import FaultCounters, ResilientStore
+            counters = FaultCounters()
+            if policy is not None:
+                reader = ResilientStore(store, policy.retry, counters,
+                                        on_exhausted=policy.on_exhausted)
+            else:
+                reader = ResilientStore(store, retry, counters,
+                                        on_exhausted="raise")
 
-    spec, params = split_params(stat)
-    base_seed = seed_from_key(key)
-    seed_int = int(base_seed)
-    fp = run_fingerprint(spec, params, B, chunk, seed_int, n_rows, dim,
-                         content=(store_content_digest(store,
-                                                       upto_row=n_rows)
-                                  if fingerprint_content else None))
+        spec, params = split_params(stat)
+        base_seed = seed_from_key(key)
+        seed_int = trace.to_host(base_seed, int)
+        fp = run_fingerprint(spec, params, B, chunk, seed_int, n_rows, dim,
+                             content=(store_content_digest(store,
+                                                           upto_row=n_rows)
+                                      if fingerprint_content else None))
 
-    # Fresh, UNALIASED device buffers for the donated carry: jnp's constant
-    # cache can hand several identical-zeros leaves the same buffer, which
-    # trips "attempt to donate the same buffer twice" on the first call.
-    def _fresh(tree):
-        return jax.tree_util.tree_map(
-            lambda a: jax.device_put(np.asarray(a)), tree)
-    states = _fresh(jax.vmap(lambda _: stat.init_state(dim))(jnp.arange(B)))
-    est = _fresh(stat.init_state(dim))
+        # Fresh, UNALIASED device buffers for the donated carry: jnp's constant
+        # cache can hand several identical-zeros leaves the same buffer, which
+        # trips "attempt to donate the same buffer twice" on the first call.
+        def _fresh(tree):
+            return jax.tree_util.tree_map(
+                lambda a: jax.device_put(trace.to_host(a)), tree)
+        states = _fresh(
+            jax.vmap(lambda _: stat.init_state(dim))(jnp.arange(B)))
+        est = _fresh(stat.init_state(dim))
 
-    start_chunk = 0
-    rows_done = 0
-    valid_rows = 0
-    prior_lost: Tuple[int, ...] = ()
-    resumed_from = None
-    if resume:
-        # validate the cursor BEFORE touching the arrays: a wrong-run
-        # checkpoint must fail on the fingerprint, not a shape mismatch
-        cur = mgr.meta().get("cursor")
-        if cur is None:
-            raise ValueError(
-                f"checkpoint under {mgr.root} has no streaming cursor — "
-                "not a bootstrap_streaming checkpoint")
-        if cur["fingerprint"] != fp:
-            raise ValueError(
-                "checkpoint fingerprint mismatch: the snapshot was taken "
-                "under a different (statistic, B, chunk, key, store) — "
-                "resuming it would silently produce a different estimator "
-                f"(checkpoint {cur['fingerprint'][:12]}…, run {fp[:12]}…)")
-        template = jax.eval_shape(lambda: (states, est))
-        (states, est), _ = mgr.restore(template)
-        states, est = _fresh(states), _fresh(est)
-        start_chunk = int(cur["next_chunk"])
-        rows_done = int(cur["rows_done"])
-        valid_rows = int(cur["valid_rows"])
-        prior_lost = tuple(cur.get("lost_splits", ()))
-        resumed_from = start_chunk
+        start_chunk = 0
+        rows_done = 0
+        valid_rows = 0
+        prior_lost: Tuple[int, ...] = ()
+        resumed_from = None
+        if resume:
+            # validate the cursor BEFORE touching the arrays: a wrong-run
+            # checkpoint must fail on the fingerprint, not a shape mismatch
+            cur = mgr.meta().get("cursor")
+            if cur is None:
+                raise ValueError(
+                    f"checkpoint under {mgr.root} has no streaming cursor — "
+                    "not a bootstrap_streaming checkpoint")
+            if cur["fingerprint"] != fp:
+                raise ValueError(
+                    "checkpoint fingerprint mismatch: the snapshot was taken "
+                    "under a different (statistic, B, chunk, key, store) — "
+                    "resuming it would silently produce a different estimator "
+                    f"(checkpoint {cur['fingerprint'][:12]}…, "
+                    f"run {fp[:12]}…)")
+            template = jax.eval_shape(lambda: (states, est))
+            (states, est), _ = mgr.restore(template)
+            states, est = _fresh(states), _fresh(est)
+            start_chunk = int(cur["next_chunk"])
+            rows_done = int(cur["rows_done"])
+            valid_rows = int(cur["valid_rows"])
+            prior_lost = tuple(cur.get("lost_splits", ()))
+            resumed_from = start_chunk
 
-    q = queue_mod.Queue(maxsize=queue_depth)
-    timings: dict = {}
-    stop = threading.Event()
-    producer = threading.Thread(target=_stage_batches,
-                                args=(reader, chunk, q, timings, stop,
-                                      rows_done, n_rows),
-                                name="earl-stream-prefetch", daemon=True)
-    t_start = time.perf_counter()
-    producer.start()
+        q = queue_mod.Queue(maxsize=queue_depth)
+        timings: dict = {}
+        stop = threading.Event()
+        producer = threading.Thread(target=_stage_batches,
+                                    args=(reader, chunk, q, timings, stop,
+                                          rows_done, n_rows),
+                                    name="earl-stream-prefetch", daemon=True)
+        t_start = time.perf_counter()
+        producer.start()
 
-    wait_s = dispatch_s = ckpt_s = 0.0
-    n_ckpts = 0
-    i = start_chunk
+        wait_s = dispatch_s = ckpt_s = 0.0
+        n_ckpts = 0
+        i = start_chunk
 
-    def _save_checkpoint():
-        # the cursor names the NEXT chunk; lost splits ride along so a
-        # resumed run keeps correcting by the right surviving fraction.
-        lost_now = tuple(sorted(set(prior_lost)
-                                | set(getattr(reader, "lost_splits", ()))))
-        mgr.save(i, (states, est), extra={"cursor": {
-            "next_chunk": i, "rows_done": rows_done,
-            "valid_rows": valid_rows, "lost_splits": list(lost_now),
-            "fingerprint": fp, "B": int(B), "chunk": int(chunk),
-            "base_seed": seed_int, "N": int(n_rows)}})
+        def _save_checkpoint():
+            # the cursor names the NEXT chunk; lost splits ride along so a
+            # resumed run keeps correcting by the right surviving fraction.
+            lost_now = tuple(sorted(set(prior_lost)
+                                    | set(getattr(reader, "lost_splits", ()))))
+            mgr.save(i, (states, est), extra={"cursor": {
+                "next_chunk": i, "rows_done": rows_done,
+                "valid_rows": valid_rows, "lost_splits": list(lost_now),
+                "fingerprint": fp, "B": int(B), "chunk": int(chunk),
+                "base_seed": seed_int, "N": int(n_rows)}})
 
-    try:
-        while True:
-            t0 = time.perf_counter()
-            item = q.get()
-            wait_s += time.perf_counter() - t0
-            if item is None:
-                break
-            if isinstance(item, BaseException):
-                raise item
-            xd, md, nb, nv = item
-            t0 = time.perf_counter()
-            states, est = _stream_chunk_jit(
-                states, est, xd, md, base_seed, jnp.asarray(i, jnp.int32),
-                params, spec, int(B))
-            dispatch_s += time.perf_counter() - t0
-            rows_done += nb
-            valid_rows += nv
-            i += 1
-            if mgr is not None and (i - start_chunk) % checkpoint_every == 0:
-                t0 = time.perf_counter()
-                _save_checkpoint()
-                ckpt_s += time.perf_counter() - t0
-                n_ckpts += 1
-    finally:
-        # a consumer-side failure (poisoned chunk, checkpoint error, kill)
-        # must not strand the producer blocked on a full queue: signal
-        # stop, drain whatever it already staged, and join it.
-        stop.set()
         try:
             while True:
-                q.get_nowait()
-        except queue_mod.Empty:
-            pass
-        producer.join(timeout=10.0)
+                with trace.span("stream.wait", chunk=i):
+                    t0 = time.perf_counter()
+                    item = q.get()
+                    wait_s += time.perf_counter() - t0
+                if item is None:
+                    break
+                if isinstance(item, BaseException):
+                    raise item
+                xd, md, nb, nv = item
+                with trace.span("stream.dispatch", chunk=i):
+                    t0 = time.perf_counter()
+                    states, est = _stream_chunk_jit(
+                        states, est, xd, md, base_seed,
+                        jnp.asarray(i, jnp.int32), params, spec, int(B))
+                    dispatch_s += time.perf_counter() - t0
+                opening.close()
+                rows_done += nb
+                valid_rows += nv
+                i += 1
+                if (mgr is not None
+                        and (i - start_chunk) % checkpoint_every == 0):
+                    t0 = time.perf_counter()
+                    _save_checkpoint()
+                    ckpt_s += time.perf_counter() - t0
+                    n_ckpts += 1
+        finally:
+            # a consumer-side failure (poisoned chunk, checkpoint error, kill)
+            # must not strand the producer blocked on a full queue: signal
+            # stop, drain whatever it already staged, and join it.
+            stop.set()
+            try:
+                while True:
+                    q.get_nowait()
+            except queue_mod.Empty:
+                pass
+            producer.join(timeout=10.0)
 
     # trailing-edge sync + a final checkpoint if the cadence missed the end
-    t0 = time.perf_counter()
-    (states, est) = jax.block_until_ready((states, est))
-    dispatch_s += time.perf_counter() - t0
+    with trace.span("stream.drain"):
+        t0 = time.perf_counter()
+        (states, est) = jax.block_until_ready((states, est))
+        drain_s = time.perf_counter() - t0
     if mgr is not None and (i - start_chunk) % checkpoint_every != 0:
         t0 = time.perf_counter()
         _save_checkpoint()
@@ -476,18 +502,19 @@ def bootstrap_streaming(store, stat: Statistic, B: int, key: jax.Array,
     # valid == N exactly and this is the plain p (ratio is exactly 1.0).
     p_eff = p * (valid_rows / n_rows)
     stat = bind_params(spec, params)
-    thetas = stat.correct(jax.vmap(stat.finalize)(states), p_eff)
-    estimate = stat.correct(stat.finalize(est), p_eff)
+    with trace.span("stream.finalize"):
+        thetas = stat.correct(jax.vmap(stat.finalize)(states), p_eff)
+        estimate = stat.correct(stat.finalize(est), p_eff)
+        report = accuracy.report_for(
+            thetas, alpha=alpha, num_groups=getattr(stat, "num_groups", None))
     return StreamingBootstrapResult(
-        estimate=estimate, thetas=thetas,
-        report=accuracy.report_for(thetas, alpha=alpha,
-                                   num_groups=getattr(stat, "num_groups",
-                                                      None)),
+        estimate=estimate, thetas=thetas, report=report,
         B=int(B), n=int(valid_rows),
         stream=StreamReport(wall_s=wall_s,
                             stage_s=timings.get("stage_s", 0.0),
                             wait_s=wait_s, dispatch_s=dispatch_s,
-                            n_chunks=i - start_chunk, rows=int(n_rows),
+                            drain_s=drain_s, n_chunks=i - start_chunk,
+                            rows=int(n_rows),
                             checkpoint_s=ckpt_s, n_checkpoints=n_ckpts,
                             resumed_from_chunk=resumed_from,
                             faults=counters, lost_splits=lost,

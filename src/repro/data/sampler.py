@@ -21,6 +21,7 @@ from typing import Optional
 import jax.numpy as jnp
 import numpy as np
 
+from repro import trace
 from repro.data.store import ShardedStore
 
 
@@ -40,31 +41,33 @@ class PermutationSampler:
         self.mode = mode
         self.N = store.N
         rng = np.random.default_rng(seed)
-        self.perm = rng.permutation(self.N)
+        with trace.span("sampler.permute"):
+            self.perm = rng.permutation(self.N)
         self._cache: Optional[np.ndarray] = None
 
     def take(self, start: int, stop: int) -> jnp.ndarray:
-        stop = min(stop, self.N)
-        rows = self.perm[start:stop]
-        if self.mode == "post_map":
-            if self._cache is None:
-                self._cache = self.store.read_all()
-            return jnp.asarray(self._cache[rows])
-        # pre-map: group the requested rows by split, read row-granular
-        split, local = self.store.locate(rows)
-        order = np.argsort(split, kind="stable")
-        out = np.empty((len(rows),) + self.store.splits[0].shape[1:],
-                       dtype=self.store.splits[0].dtype)
-        i = 0
-        while i < len(order):
-            j = i
-            s = split[order[i]]
-            while j < len(order) and split[order[j]] == s:
-                j += 1
-            sel = order[i:j]
-            out[sel] = self.store.read_rows(int(s), local[sel])
-            i = j
-        return jnp.asarray(out)
+        with trace.span("sampler.take"):
+            stop = min(stop, self.N)
+            rows = self.perm[start:stop]
+            if self.mode == "post_map":
+                if self._cache is None:
+                    self._cache = self.store.read_all()
+                return jnp.asarray(self._cache[rows])
+            # pre-map: group the requested rows by split, read row-granular
+            split, local = self.store.locate(rows)
+            order = np.argsort(split, kind="stable")
+            out = np.empty((len(rows),) + self.store.splits[0].shape[1:],
+                           dtype=self.store.splits[0].dtype)
+            i = 0
+            while i < len(order):
+                j = i
+                s = split[order[i]]
+                while j < len(order) and split[order[j]] == s:
+                    j += 1
+                sel = order[i:j]
+                out[sel] = self.store.read_rows(int(s), local[sel])
+                i = j
+            return jnp.asarray(out)
 
 
 class StratifiedSampler(PermutationSampler):

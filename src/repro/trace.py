@@ -1,0 +1,57 @@
+"""The program's own spans and counters.
+
+``span(name, **meta)`` marks a stretch of host work as ``earl.<name>`` in
+the profiler's trace (``jax.profiler.TraceAnnotation``), on the same clock
+as the device's operations; keyword arguments ride along as the span's
+metadata (``chunk=i``, ``round=k``).  A span costs about a microsecond when
+no profiler runs and reaches a trace only when one does, e.g.::
+
+    jax.profiler.start_trace("/tmp/earl-trace")
+    session.run(key)
+    jax.profiler.stop_trace()
+
+``count(name, n)`` adds to a process-wide counter, and ``counts()`` returns
+a snapshot of all of them; callers read the difference over the work they
+care about.  ``host_sync`` counts the reads of a device value on the host
+(``to_host``) on the session and stream paths, each of which waits for the
+device; ``session_run`` counts ``EarlSession.run`` calls, so that
+``host_sync`` per session can be read without a snapshot around each.
+"""
+from __future__ import annotations
+
+import threading
+from typing import Callable, Dict
+
+import jax
+import numpy as np
+
+PREFIX = "earl."
+HOST_SYNC = "host_sync"
+
+_lock = threading.Lock()
+_counts: Dict[str, int] = {}
+
+
+def span(name: str, **meta) -> jax.profiler.TraceAnnotation:
+    """A context manager that marks its block as ``earl.<name>``."""
+    return jax.profiler.TraceAnnotation(PREFIX + name, **meta)
+
+
+def count(name: str, n: int = 1) -> None:
+    """Add ``n`` to the counter ``name`` (thread-safe)."""
+    with _lock:
+        _counts[name] = _counts.get(name, 0) + n
+
+
+def counts() -> Dict[str, int]:
+    """A snapshot of every counter."""
+    with _lock:
+        return dict(_counts)
+
+
+def to_host(value, convert: Callable = np.asarray):
+    """``convert(value)``, counted as one ``host_sync`` when ``value`` is a
+    device array (the read then waits for the device)."""
+    if isinstance(value, jax.Array):
+        count(HOST_SYNC)
+    return convert(value)

@@ -174,6 +174,18 @@ class TestStreamingBitwiseEqualsChunked:
         assert sr.n_chunks == 4 and sr.rows == 10_000
         assert sr.wall_s > 0 and sr.dispatch_s >= 0 and sr.wait_s >= 0
 
+    def test_stream_report_splits_dispatch_from_drain(self):
+        """``dispatch_s`` is the enqueue alone and ``drain_s`` the trailing
+        wait for the device: disjoint stretches of the main thread, so with
+        ``wait_s`` they fit inside the wall time; the answer is unchanged."""
+        rc, rs = self._both(StatisticGroup(
+            [Mean(), Quantile(0.25, lo=-4.0, hi=4.0, nbins=32)]))
+        sr = rs.stream
+        assert sr.drain_s >= 0 and sr.dispatch_s > 0
+        assert sr.dispatch_s + sr.drain_s + sr.wait_s <= sr.wall_s
+        _tree_bitwise(rc.thetas, rs.thetas)
+        _tree_bitwise(rc.estimate, rs.estimate)
+
     def test_reads_store_exactly_once(self):
         store = _store()
         store.stats.reset()
