@@ -13,6 +13,10 @@
   random permutation of [0, N); ``take(a, b)`` returns permutation rows
   [a, b), so growing samples are prefix-extends (uniform without
   replacement — DESIGN.md §7.2) and delta maintenance gets pure Δs rows.
+  The permutation is drawn lazily, only as far as ``take`` reaches: a
+  take that stops at or below the cut-off K(N) = ⌈N/8⌉ costs the new
+  positions it draws, and the whole order is materialised only when a
+  caller reaches past K or reads ``perm``.
 """
 from __future__ import annotations
 
@@ -25,12 +29,42 @@ from repro import trace
 from repro.data.store import ShardedStore
 
 
+#: candidates drawn at a time by the lazy prefix.  A constant, so the
+#: candidate stream, and with it every prefix, is a function of the seed
+#: alone, however the takes are split.
+_BLOCK = 1 << 12
+
+
+def lazy_cutoff(N: int) -> int:
+    """K(N) = ⌈N/8⌉: how far the permutation's prefix is drawn lazily.
+    Up to K fewer than 1/8 of the candidates are duplicates."""
+    return -(-N // 8)
+
+
 class PermutationSampler:
     """Uniform without-replacement prefixes via a fixed permutation.
 
     ``mode="pre_map"`` reads row-granular (cheap); ``mode="post_map"``
     materializes the full store on first touch (exact counts, expensive) —
     both expose identical take() semantics so EarlSession is agnostic.
+
+    The permutation is a uniform random permutation of [0, N), drawn in two
+    parts from independent children of ``seed``:
+
+    * the prefix up to K = ``lazy_cutoff(N)``: the first occurrences, in
+      stream order, of a stream of candidates drawn uniformly from [0, N)
+      with replacement, in blocks of ``_BLOCK``.  A bitmap of N bits tests
+      each candidate against the rows already drawn.  Any prefix is then a
+      uniform sample without replacement in uniform random order, and it is
+      drawn only as far as a take asks: O(new rows) a take.
+    * the rest: the complement of the K-prefix in a uniform order of its
+      own, drawn once, when a take reaches past K or ``perm`` is read.
+
+    ``take(a, b)`` is a function of (seed, N, a, b) alone, whatever takes
+    came before it.  Counters (``repro.trace``): ``sampler_rows_drawn``,
+    the prefix positions handed out by the lazy draw; ``sampler_redraws``,
+    the candidates it drew and rejected as duplicates;
+    ``sampler_full_permute``, each materialisation of the whole order.
     """
 
     def __init__(self, store: ShardedStore, seed: int = 0,
@@ -40,15 +74,75 @@ class PermutationSampler:
         self.store = store
         self.mode = mode
         self.N = store.N
-        rng = np.random.default_rng(seed)
         with trace.span("sampler.permute"):
-            self.perm = rng.permutation(self.N)
+            prefix_seq, self._rest_seq = np.random.SeedSequence(
+                seed).spawn(2)
+            self._rng = np.random.default_rng(prefix_seq)
+            self._K = lazy_cutoff(self.N)
+            self._seen = np.zeros((self.N + 7) // 8, np.uint8)
+            self._buf = np.empty(0, np.int64)   # prefix drawn so far
+            self._n_drawn = 0       # prefix positions of all blocks drawn
+            self._hi = 0            # prefix positions handed out
+            self._order: Optional[np.ndarray] = None
         self._cache: Optional[np.ndarray] = None
+
+    def _draw_block(self) -> None:
+        c = self._rng.integers(0, self.N, size=_BLOCK)
+        _, first = np.unique(c, return_index=True)
+        new = np.zeros(_BLOCK, bool)
+        new[first] = True                       # first in the block
+        byte = c >> 3
+        bit = np.left_shift(1, c & 7).astype(np.uint8)
+        new &= (self._seen[byte] & bit) == 0    # not in an earlier block
+        pos = np.flatnonzero(new)
+        np.bitwise_or.at(self._seen, byte[pos], bit[pos])
+        trace.count("sampler_redraws", _BLOCK - len(pos))
+        end = self._n_drawn + len(pos)
+        if end > len(self._buf):
+            grown = np.empty(max(end, 2 * len(self._buf)), np.int64)
+            grown[:self._n_drawn] = self._buf[:self._n_drawn]
+            self._buf = grown
+        self._buf[self._n_drawn:end] = c[pos]
+        self._n_drawn = end
+
+    def _draw(self, stop: int) -> None:
+        """Hand out the prefix up to ``stop`` ≤ K positions."""
+        if stop <= self._hi:
+            return
+        with trace.span("sampler.draw"):
+            while self._n_drawn < stop:
+                self._draw_block()
+            trace.count("sampler_rows_drawn", stop - self._hi)
+            self._hi = stop
+
+    @property
+    def perm(self) -> np.ndarray:
+        """The whole order, materialised on first use (O(N)); its first k
+        entries are ``take(0, k)``'s rows."""
+        if self._order is None:
+            self._draw(self._K)
+            trace.count("sampler_full_permute")
+            order = np.empty(self.N, np.int64)
+            order[:self._K] = self._buf[:self._K]
+            outside = np.ones(self.N, bool)
+            outside[order[:self._K]] = False
+            rest = order[self._K:]
+            rest[:] = np.flatnonzero(outside)
+            np.random.default_rng(self._rest_seq).shuffle(rest)
+            self._order = order
+            self._seen = self._buf = None
+        return self._order
+
+    def _rows(self, start: int, stop: int) -> np.ndarray:
+        if self._order is None and stop <= self._K:
+            self._draw(stop)
+            return self._buf[start:stop]
+        return self.perm[start:stop]
 
     def take(self, start: int, stop: int) -> jnp.ndarray:
         with trace.span("sampler.take"):
             stop = min(stop, self.N)
-            rows = self.perm[start:stop]
+            rows = self._rows(start, stop)
             if self.mode == "post_map":
                 if self._cache is None:
                     self._cache = self.store.read_all()
@@ -129,15 +223,16 @@ class StratifiedSampler(PermutationSampler):
         self.stratum_sizes = np.bincount(keys, minlength=self.num_groups)
 
         # stride-schedule the base permutation (see class docstring)
-        kperm = keys[self.perm]
+        base = self.perm
+        kperm = keys[base]
         order = np.argsort(kperm, kind="stable")
         sorted_k = kperm[order]
         starts = np.searchsorted(sorted_k, np.arange(self.num_groups))
         ranks = np.empty(self.N, np.int64)
         ranks[order] = np.arange(self.N) - starts[sorted_k]
         vtime = (ranks + 1) / self.shares[kperm]
-        self.perm = self.perm[np.argsort(vtime, kind="stable")]
-        self._kperm = keys[self.perm]
+        self._order = base[np.argsort(vtime, kind="stable")]
+        self._kperm = keys[self._order]
 
     def stratum_counts(self, stop: int) -> np.ndarray:
         """Rows of each key inside the prefix [0, stop) — with

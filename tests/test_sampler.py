@@ -1,11 +1,16 @@
 """Sampling over the sharded store (paper §3.3): uniformity, prefix
-semantics, read accounting, pre- vs post-map."""
+semantics, the lazily drawn permutation, read accounting, pre- vs
+post-map."""
+import itertools
+
 import numpy as np
 import pytest
 from scipy import stats as sps
 
+from repro import trace
 from repro.data import (PostMapSampler, PreMapSampler, ShardedStore,
                         StratifiedSampler, synthetic_numeric)
+from repro.data.sampler import PermutationSampler, lazy_cutoff
 
 
 def _store(n=50_000, nvals=20, interleave=True):
@@ -39,6 +44,153 @@ class TestUniformity:
         sampler = PreMapSampler(store, seed=2)
         s = np.asarray(sampler.take(0, 10_000)).ravel()
         assert len(np.unique(s)) == 10_000
+
+
+def _ids(N, split_rows=1024):
+    """A store whose one column holds each row's index."""
+    return ShardedStore.from_array(np.arange(N, dtype=np.float32)[:, None],
+                                   split_rows, interleave=False)
+
+
+def _rows(sampler, start, stop):
+    return np.asarray(sampler.take(start, stop)).ravel().astype(np.int64)
+
+
+def _split_takes(s, stop):
+    return np.concatenate([_rows(s, a, min(a + 257, stop))
+                           for a in range(0, stop, 257)])
+
+
+def _resume_mid_prefix(s, stop):
+    # a fresh sampler's first take starts where a checkpoint left off
+    return np.concatenate([_rows(PreMapSampler(s.store, seed=9), 0, 1000),
+                           _rows(s, 1000, stop)])
+
+
+def _after_perm(s, stop):
+    s.perm
+    return _rows(s, 0, stop)
+
+
+def _perm_after_takes(s, stop):
+    _rows(s, 0, stop // 3)
+    return s.perm[:stop].copy()
+
+
+class TestLazyPermutation:
+    """The permutation's prefix is drawn only as far as ``take`` reaches;
+    ``take(a, b)`` is a function of (seed, N, a, b) alone."""
+
+    N = 50_000
+
+    @pytest.mark.parametrize("pattern", [
+        _split_takes, _resume_mid_prefix, _after_perm, _perm_after_takes],
+        ids=["split-takes", "fresh-resume", "after-perm",
+             "perm-after-takes"])
+    @pytest.mark.parametrize("stop", [3000, 9000],
+                             ids=["inside-K", "past-K"])
+    def test_rows_do_not_depend_on_the_calls_before(self, pattern, stop):
+        store = _ids(self.N)
+        one_take = _rows(PreMapSampler(store, seed=9), 0, stop)
+        assert lazy_cutoff(self.N) == 6250
+        np.testing.assert_array_equal(
+            pattern(PreMapSampler(store, seed=9), stop), one_take)
+
+    @pytest.mark.parametrize("N", [1, 2, 5, 8, 9, 1000, 4097, 50_000])
+    def test_whole_order_is_a_permutation_across_the_cutoff(self, N):
+        s = PreMapSampler(_ids(N), seed=N)
+        head = _rows(s, 0, lazy_cutoff(N))
+        assert len(np.unique(head)) == len(head)
+        perm = s.perm
+        np.testing.assert_array_equal(perm[:len(head)], head)
+        np.testing.assert_array_equal(np.sort(perm), np.arange(N))
+        np.testing.assert_array_equal(_rows(s, 0, N), perm)
+
+    def test_other_seeds_give_other_prefixes(self):
+        store = _ids(self.N)
+        a, b = (_rows(PreMapSampler(store, seed=k), 0, 100) for k in (1, 2))
+        assert not np.array_equal(a, b)
+
+    def test_all_orders_of_five_are_equally_likely(self):
+        """The whole order (K = 1 lazy position, then the shuffled rest):
+        each of the 5! orders over 12,000 seeds."""
+        store = _ids(5)
+        index = {p: i for i, p in
+                 enumerate(itertools.permutations(range(5)))}
+        counts = np.zeros(120)
+        for seed in range(12_000):
+            counts[index[tuple(PermutationSampler(store, seed).perm)]] += 1
+        chi2, p = sps.chisquare(counts)
+        assert p > 0.001, f"orders not uniform: chi2={chi2}, p={p}"
+
+    def test_every_position_is_uniform_across_the_cutoff(self):
+        """N = 16, K = 2: the value at each position of the whole order,
+        over 8,000 seeds."""
+        store = _ids(16)
+        table = np.zeros((16, 16))
+        for seed in range(8_000):
+            table[np.arange(16), PermutationSampler(store, seed).perm] += 1
+        for pos, row in enumerate(table):
+            chi2, p = sps.chisquare(row)
+            assert p > 0.001, f"position {pos}: chi2={chi2}, p={p}"
+
+    def test_lazy_prefix_is_a_uniform_sample(self):
+        """N = 1000, 100 rows (K = 125): the first row, each row's inclusion
+        and the co-inclusion of every pair among rows 0-19 (which share
+        bitmap bytes), over 10,000 seeds."""
+        N, k, S = 1000, 100, 10_000
+        store = _ids(N)
+        first = np.zeros(N)
+        included = np.zeros(N)
+        pairs = np.zeros((20, 20))
+        for seed in range(S):
+            rows = _rows(PreMapSampler(store, seed=seed), 0, k)
+            first[rows[0]] += 1
+            included[rows] += 1
+            low = np.zeros(20)
+            low[rows[rows < 20]] = 1
+            pairs += np.outer(low, low)
+        assert included.sum() == S * k
+        for name, counts in (("first", first), ("inclusion", included),
+                             ("pairs", pairs[np.triu_indices(20, 1)])):
+            chi2, p = sps.chisquare(counts)
+            assert p > 0.001, f"{name} not uniform: chi2={chi2}, p={p}"
+        expected = S * k * (k - 1) / (N * (N - 1))
+        assert abs(pairs[np.triu_indices(20, 1)].mean() / expected - 1) < 0.05
+
+    def test_a_pilot_from_a_large_store_materialises_nothing(self):
+        store = ShardedStore.from_array(np.zeros((1 << 20, 1), np.float32),
+                                        1 << 16, interleave=False)
+        before = trace.counts()
+        s = PreMapSampler(store, seed=2147483901)
+        s.take(0, 8192)
+        s.take(0, 8192)                      # the round reuses the pilot
+        after = trace.counts()
+        got = {name: after.get(name, 0) - before.get(name, 0) for name in
+               ("sampler_rows_drawn", "sampler_redraws",
+                "sampler_full_permute")}
+        assert got["sampler_full_permute"] == 0
+        assert got["sampler_rows_drawn"] == 8192
+        # three blocks of 4096 candidates from 2^20 rows: ~72 duplicates
+        assert 0 < got["sampler_redraws"] < 150
+        assert s._order is None
+
+    def test_counters_do_not_depend_on_how_the_takes_split(self):
+        store = _ids(self.N)
+
+        def drawn(takes):
+            s = PreMapSampler(store, seed=3)
+            before = trace.counts()
+            for a, b in takes:
+                s.take(a, b)
+            after = trace.counts()
+            return [after.get(n, 0) - before.get(n, 0) for n in
+                    ("sampler_rows_drawn", "sampler_redraws")]
+
+        one = drawn([(0, 6000)])
+        assert one == drawn([(0, 10), (10, 4100), (4100, 6000)])
+        assert one == drawn([(5000, 6000), (0, 100)])
+        assert one[0] == 6000
 
 
 class TestReadAccounting:

@@ -7,6 +7,9 @@
 * ``host_sync`` counts every read of a device value on the session and
   stream paths: for a session it follows from the B̂ ladder, ``l`` and the
   rounds.
+* The sampler draws its permutation lazily: a session that stays inside
+  its pilot draws the pilot's rows and materialises nothing; the exact
+  job's fallback materialises the whole order once.
 * Spans only observe: a session's answer is the same with them recorded.
 """
 import contextlib
@@ -21,6 +24,7 @@ from repro import trace
 from repro.core import EarlSession, Mean, Quantile, Std, StatisticGroup
 from repro.core.streaming import bootstrap_streaming
 from repro.data import PreMapSampler, ShardedStore
+from repro.data.sampler import lazy_cutoff
 
 
 class SpanLog:
@@ -71,7 +75,9 @@ def _store():
 
 
 def _session(stat, sigma, backend=None):
-    return EarlSession(PreMapSampler(_store(), seed=1), stat, sigma=sigma,
+    # the sampler's seed sets the pilot, and with it SSABE's n̂: under this
+    # one the cases below run one round, several rounds, or fall back
+    return EarlSession(PreMapSampler(_store(), seed=64), stat, sigma=sigma,
                        backend=backend)
 
 
@@ -113,6 +119,46 @@ def test_fallback_runs_inside_a_full_job_span(spans):
     assert spans.parents("earl.sampler.take") == {"earl.session.pilot",
                                                   "earl.session.full_job"}
     assert not spans.named("earl.session.round")
+
+
+def test_lazy_draw_spans_nest_under_take(spans):
+    sess = _session(Mean(), sigma=0.0001)
+    out = sess.run(jax.random.PRNGKey(0))
+    assert not out.fell_back
+    # the pilot's take draws the prefix; a round past it draws more
+    assert spans.parents("earl.sampler.draw") == {"earl.sampler.take"}
+    assert 1 <= len(spans.named("earl.sampler.draw")) <= 1 + out.iterations
+
+
+def _sampler_counts(before):
+    after = trace.counts()
+    return {k: after.get(k, 0) - before.get(k, 0)
+            for k in ("sampler_rows_drawn", "sampler_full_permute")}
+
+
+def test_one_round_session_draws_the_pilot_and_permutes_nothing():
+    sess = _session(Mean(), sigma=0.005)
+    before = trace.counts()
+    out = sess.run(jax.random.PRNGKey(0))
+    n_pilot = min(out.N, sess.max_pilot,
+                  max(sess.min_pilot, int(sess.p_pilot * out.N)))
+    assert not out.fell_back and out.iterations == 1
+    # the round takes the pilot's prefix again: nothing new is drawn
+    assert out.n_used == n_pilot
+    assert _sampler_counts(before) == {"sampler_rows_drawn": n_pilot,
+                                       "sampler_full_permute": 0}
+
+
+def test_fallback_materialises_the_order_once():
+    sess = _session(StatisticGroup((Mean(), Std())), sigma=0.005,
+                    backend="fused_rng")
+    before = trace.counts()
+    out = sess.run(jax.random.PRNGKey(0))
+    assert out.fell_back
+    got = _sampler_counts(before)
+    assert got["sampler_full_permute"] == 1
+    # the exact job's take(0, N) draws the lazy prefix up to K first
+    assert got["sampler_rows_drawn"] == lazy_cutoff(out.N)
 
 
 @pytest.mark.parametrize("stat,sigma,backend,members", [
