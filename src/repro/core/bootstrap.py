@@ -51,14 +51,16 @@ reduce in the same shard order) — the single-device path stays the oracle.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import inspect
-from functools import partial
+from functools import lru_cache, partial
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro import trace
 from repro.core import accuracy
 from repro.core.reduce_api import (Statistic, _as_2d, bind_params,
                                    split_params)
@@ -249,11 +251,10 @@ def sharded_fused_states(stat: Statistic, base_seed, x2: jax.Array, B: int,
     if chunk is not None and not (isinstance(step, int) and step == 0):
         raise ValueError("chunk= and step= are mutually exclusive (their "
                          "stream indices would alias; see docstring)")
-    n, dim = x2.shape
-    m = -(-n // nshards)                 # ceil: local rows per shard
-    xp = jnp.pad(x2, ((0, nshards * m - n), (0, 0)))
-
     if mesh is None:
+        n = x2.shape[0]
+        m = -(-n // nshards)             # ceil: local rows per shard
+        xp = jnp.pad(x2, ((0, nshards * m - n), (0, 0)))
         states, est = None, None
         for i in range(nshards):
             nv = min(max(n - i * m, 0), m)
@@ -267,9 +268,29 @@ def sharded_fused_states(stat: Statistic, base_seed, x2: jax.Array, B: int,
                 jax.vmap(stat.merge)(states, si)
         return (states, est) if with_estimate else states
 
+    spec, params = split_params(stat)
+    return _mesh_fold_jit(x2, jnp.asarray(base_seed, jnp.int32),
+                          jnp.asarray(step, jnp.int32), params, spec, int(B),
+                          mesh, data_axis, chunk, bool(with_estimate))
+
+
+@partial(jax.jit, static_argnames=("stat", "B", "mesh", "data_axis", "chunk",
+                                   "with_estimate"))
+def _mesh_fold_jit(x2, base_seed, step, params, stat, B, mesh, data_axis,
+                   chunk, with_estimate):
+    """The mesh branch of ``sharded_fused_states`` as one program, cached
+    per (statistic spec, B, mesh, axis, chunk, with_estimate) and the
+    shapes of x: a warm call traces and builds nothing.  Called inside
+    another jit it inlines there."""
     from jax.sharding import PartitionSpec as P
+    stat = bind_params(stat, params)
+    nshards = int(mesh.shape[data_axis])
+    n = x2.shape[0]
+    m = -(-n // nshards)
+    xp = jnp.pad(x2, ((0, nshards * m - n), (0, 0)))
 
     def shard_fn(x_local, seed, step_):
+        trace.count(trace.MESH_TRACES)        # runs only while JAX traces
         i = jax.lax.axis_index(data_axis)
         nv = jnp.clip(n - i * m, 0, m)
         st = _shard_local_states(stat, seed, x_local, B, i, nshards, nv,
@@ -285,8 +306,36 @@ def sharded_fused_states(stat: Statistic, base_seed, x2: jax.Array, B: int,
                        in_specs=(P(data_axis, None), P(), P()),
                        out_specs=(P(), P()) if with_estimate else P(),
                        check_vma=False)
-    return fn(xp, jnp.asarray(base_seed, jnp.int32),
-              jnp.asarray(step, jnp.int32))
+    return fn(xp, base_seed, step)
+
+
+def state_nbytes(tree) -> int:
+    """Bytes of the leaves of a state pytree (arrays or shape structs)."""
+    return sum(leaf.size * leaf.dtype.itemsize
+               for leaf in jax.tree_util.tree_leaves(tree))
+
+
+@contextlib.contextmanager
+def mesh_fold(mesh, data_axis: str, n: int):
+    """Host side of one fold of ``n`` rows: with a mesh, the
+    ``earl.mesh.fold`` span around its dispatch, then one ``mesh_folds``
+    and, in ``mesh_psum_bytes``, the bytes of the state trees handed to the
+    yielded ``psummed``; without one, nothing."""
+    if mesh is None:
+        yield lambda tree: None
+        return
+    trees = []
+    with trace.span("mesh.fold", n=n, shards=mesh.shape[data_axis]):
+        yield trees.append
+    trace.count(trace.MESH_FOLDS)
+    trace.count(trace.MESH_PSUM_BYTES, state_nbytes(trees))
+
+
+@lru_cache(maxsize=None)
+def _resample_states_shape(stat: Statistic, B: int, dim: int):
+    """Shapes of ``B`` resample states of ``stat`` over ``dim`` columns."""
+    return jax.eval_shape(
+        lambda: jax.vmap(lambda _: stat.init_state(dim))(jnp.arange(B)))
 
 
 def multinomial_counts(key: jax.Array, B: int, n: int,
@@ -408,9 +457,13 @@ def bootstrap(values: jax.Array, stat: Statistic, B: int, key: jax.Array,
         raise TypeError("stat must be a reduce_api.Statistic")
     _check_fused_backend(backend, engine, mesh)
     spec, params = split_params(stat)
-    thetas, estimate = _bootstrap_jit(values, key, params, spec, int(B),
-                                      engine, bool(use_kernel), backend,
-                                      mesh, data_axis)
+    with mesh_fold(mesh, data_axis, values.shape[0]) as psummed:
+        thetas, estimate = _bootstrap_jit(values, key, params, spec, int(B),
+                                          engine, bool(use_kernel), backend,
+                                          mesh, data_axis)
+        if mesh is not None:        # the states psummed inside the program
+            psummed(_resample_states_shape(spec, int(B),
+                                           _as_2d(values).shape[1]))
     thetas = stat.correct(thetas, p)
     estimate = stat.correct(estimate, p)
     return BootstrapResult(
@@ -455,9 +508,11 @@ def bootstrap_chunked(values: jax.Array, stat: Statistic, B: int,
     n, dim = x.shape
 
     if mesh is not None:
-        states, est = sharded_fused_states(stat, seed_from_key(key), x, B,
-                                           mesh=mesh, data_axis=data_axis,
-                                           chunk=chunk, with_estimate=True)
+        with mesh_fold(mesh, data_axis, n) as psummed:
+            states, est = sharded_fused_states(
+                stat, seed_from_key(key), x, B, mesh=mesh,
+                data_axis=data_axis, chunk=chunk, with_estimate=True)
+            psummed((states, est))
     else:
         pad = (-n) % chunk
         xp = jnp.pad(x, ((0, pad), (0, 0)))

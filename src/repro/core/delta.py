@@ -33,7 +33,7 @@ import numpy as np
 
 from repro.core import accuracy
 from repro.core.bootstrap import (BootstrapResult, fused_resample_states,
-                                  offset_seed, poisson_weights,
+                                  mesh_fold, offset_seed, poisson_weights,
                                   seed_from_key, sharded_fused_states)
 from repro.core.reduce_api import (Statistic, StatisticGroup, _as_2d,
                                    bind_params, split_params)
@@ -106,9 +106,11 @@ def poisson_delta_extend(pd: PoissonDelta, new_values: jax.Array
     x = _as_2d(new_values)
     dn = x.shape[0]
     spec, params = split_params(pd.stat)
-    states, est_state = _pd_extend_jit(pd.states, pd.est_state, pd.key,
-                                       pd.step, x, params, spec, pd.B,
-                                       pd.backend, pd.mesh, pd.data_axis)
+    with mesh_fold(pd.mesh, pd.data_axis, dn) as psummed:
+        states, est_state = _pd_extend_jit(pd.states, pd.est_state, pd.key,
+                                           pd.step, x, params, spec, pd.B,
+                                           pd.backend, pd.mesh, pd.data_axis)
+        psummed(states)
     return dataclasses.replace(pd, states=states, est_state=est_state,
                                n=pd.n + dn, step=pd.step + 1)
 

@@ -26,8 +26,8 @@ import numpy as np
 from repro import trace
 from repro.core import accuracy
 from repro.core.bootstrap import (bootstrap_thetas, fused_resample_states,
-                                  seed_from_key, sharded_fused_states,
-                                  weights_for)
+                                  mesh_fold, seed_from_key,
+                                  sharded_fused_states, weights_for)
 from repro.core.delta import poisson_delta_extend, poisson_delta_init, \
     poisson_delta_result
 from repro.core.reduce_api import Statistic, _as_2d
@@ -92,9 +92,11 @@ def estimate_B(values: jax.Array, stat: Statistic, tau: float,
         # give nested B as before.  With a mesh the pilot shards over the
         # data axis and only the states psum.
         if mesh is not None:
-            states = sharded_fused_states(stat, seed_from_key(key), x,
-                                          B_max, mesh=mesh,
-                                          data_axis=data_axis)
+            with mesh_fold(mesh, data_axis, n) as psummed:
+                states = sharded_fused_states(stat, seed_from_key(key), x,
+                                              B_max, mesh=mesh,
+                                              data_axis=data_axis)
+                psummed(states)
         else:
             states = fused_resample_states(stat, seed_from_key(key), x,
                                            B_max)

@@ -16,6 +16,10 @@ care about.  ``host_sync`` counts the reads of a device value on the host
 (``to_host``) on the session and stream paths, each of which waits for the
 device; ``session_run`` counts ``EarlSession.run`` calls, so that
 ``host_sync`` per session can be read without a snapshot around each.
+On a mesh, ``mesh_folds`` counts the sharded folds the host dispatches
+(each inside an ``earl.mesh.fold`` span), ``mesh_psum_bytes`` the bytes of
+the state pytrees they psum, and ``mesh_traces`` the times JAX traces a
+sharded fold's body, which a warm fold does not.
 """
 from __future__ import annotations
 
@@ -27,6 +31,9 @@ import numpy as np
 
 PREFIX = "earl."
 HOST_SYNC = "host_sync"
+MESH_FOLDS = "mesh_folds"
+MESH_TRACES = "mesh_traces"
+MESH_PSUM_BYTES = "mesh_psum_bytes"
 
 _lock = threading.Lock()
 _counts: Dict[str, int] = {}
