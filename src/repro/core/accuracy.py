@@ -77,15 +77,7 @@ class AccuracyReport:
 
     @staticmethod
     def from_thetas(thetas: jax.Array, alpha: float = 0.05) -> "AccuracyReport":
-        lo, hi = percentile_ci(thetas, alpha)
-        return AccuracyReport(
-            cv=trace.to_host(coefficient_of_variation(thetas), float),
-            se=trace.to_host(standard_error(thetas), float),
-            rel_halfwidth=trace.to_host(relative_halfwidth(thetas), float),
-            ci_lo=lo,
-            ci_hi=hi,
-            boot_mean=jnp.mean(_flatten_thetas(thetas), axis=0),
-        )
+        return report_for(thetas, alpha)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -156,6 +148,70 @@ class KeyedAccuracyReport(GroupAccuracyReport):
         return max(range(len(cvs)), key=lambda g: cvs[g])
 
 
+#: the scalar gates of a report, in the order ``report_scalars`` stacks them
+SCALARS = ("cv", "se", "rel_halfwidth")
+
+
+def _numbers(thetas, alpha: float) -> dict:
+    lo, hi = percentile_ci(thetas, alpha)
+    return dict(cv=coefficient_of_variation(thetas),
+                se=standard_error(thetas),
+                rel_halfwidth=relative_halfwidth(thetas),
+                ci_lo=lo, ci_hi=hi,
+                boot_mean=jnp.mean(_flatten_thetas(thetas), axis=0))
+
+
+def _parts(thetas, num_groups=None) -> tuple:
+    """The theta arrays a report has a member for: a StatisticGroup's
+    per-member thetas, with ``num_groups`` the (B, G, ...) thetas of a
+    keyed statistic split per key along axis 1, else the one array."""
+    if isinstance(thetas, (tuple, list)):
+        return tuple(thetas)
+    if num_groups is not None:
+        thetas = jnp.asarray(thetas)
+        return tuple(thetas[:, g] for g in range(int(num_groups)))
+    return (thetas,)
+
+
+def report_numbers(thetas, alpha: float = 0.05, num_groups=None):
+    """Everything a report holds, as device arrays and jittable: a dict of
+    ``cv``, ``se``, ``rel_halfwidth``, ``ci_lo``, ``ci_hi`` and
+    ``boot_mean`` for a (B, ...) theta array, or a tuple of such dicts, one
+    per member of a StatisticGroup's tuple of thetas or, with
+    ``num_groups``, one per key along axis 1 of (B, G, ...) thetas."""
+    numbers = tuple(_numbers(t, alpha) for t in _parts(thetas, num_groups))
+    if isinstance(thetas, (tuple, list)) or num_groups is not None:
+        return numbers
+    return numbers[0]
+
+
+def _members(numbers) -> tuple:
+    return (numbers,) if isinstance(numbers, dict) else tuple(numbers)
+
+
+def report_scalars(numbers) -> jax.Array:
+    """(members, 3): each member's (or key's) ``SCALARS`` in one array, so
+    that the host reads them all in one transfer.  Jittable."""
+    return jnp.stack([jnp.stack([m[k] for k in SCALARS])
+                      for m in _members(numbers)])
+
+
+def report_from_numbers(numbers, scalars, num_groups=None, p_keys=None):
+    """The report of ``report_numbers``' output, its scalar gates taken
+    from ``scalars`` (host numbers, one row of ``SCALARS`` per member)."""
+    reports = tuple(
+        AccuracyReport(*(float(v) for v in row), ci_lo=m["ci_lo"],
+                       ci_hi=m["ci_hi"], boot_mean=m["boot_mean"])
+        for m, row in zip(_members(numbers), scalars))
+    if isinstance(numbers, dict):
+        return reports[0]
+    if num_groups is not None:
+        return KeyedAccuracyReport(
+            reports, p_keys=None if p_keys is None
+            else tuple(float(p) for p in p_keys))
+    return GroupAccuracyReport(reports)
+
+
 def report_for(thetas, alpha: float = 0.05, num_groups=None, p_keys=None):
     """AccuracyReport for a (B, ...) theta array, a GroupAccuracyReport
     for the tuple of per-member thetas a StatisticGroup produces, or — when
@@ -163,18 +219,21 @@ def report_for(thetas, alpha: float = 0.05, num_groups=None, p_keys=None):
     GroupedStatistic) — a KeyedAccuracyReport splitting the (B, G, ...)
     thetas into per-key reports along axis 1.  ``p_keys`` is carried onto
     the keyed report for introspection (the thetas must already be
-    per-key corrected)."""
-    if isinstance(thetas, (tuple, list)):
-        return GroupAccuracyReport(tuple(
-            AccuracyReport.from_thetas(t, alpha) for t in thetas))
-    if num_groups is not None:
-        thetas = jnp.asarray(thetas)
-        return KeyedAccuracyReport(tuple(
-            AccuracyReport.from_thetas(thetas[:, g], alpha)
-            for g in range(int(num_groups))),
-            p_keys=None if p_keys is None
-            else tuple(float(p) for p in p_keys))
-    return AccuracyReport.from_thetas(thetas, alpha)
+    per-key corrected).  Eager: each scalar gate is its own read on the
+    host; the session's compiled result step reads them all at once
+    (``report_scalars``)."""
+    numbers = report_numbers(thetas, alpha, num_groups)
+    scalars = [[trace.to_host(m[k], float) for k in SCALARS]
+               for m in _members(numbers)]
+    return report_from_numbers(numbers, scalars, num_groups, p_keys)
+
+
+def worst_cv(thetas, num_groups=None) -> jax.Array:
+    """c_v of a theta distribution — for a StatisticGroup's tuple of
+    per-member thetas the WORST member, and with ``num_groups`` (a
+    GroupedStatistic's (B, G, ...) thetas) the WORST KEY.  Jittable."""
+    return jnp.max(jnp.stack([coefficient_of_variation(t)
+                              for t in _parts(thetas, num_groups)]))
 
 
 def theoretical_num_bootstraps(eps0: float) -> int:

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import trace
 from repro.core import accuracy
 from repro.core.bootstrap import (BootstrapResult, fused_resample_states,
                                   mesh_fold, offset_seed, poisson_weights,
@@ -65,10 +66,20 @@ def poisson_delta_init(stat: Statistic, B: int, dim: int, key: jax.Array,
     if mesh is not None and backend != "fused_rng":
         raise ValueError("mesh= requires backend='fused_rng' (sharded delta "
                          "maintenance psums fused states)")
-    states = jax.vmap(lambda _: stat.init_state(dim))(jnp.arange(B))
+    spec, params = split_params(stat)
+    states, est_state = _pd_init_jit(params, spec, int(B), int(dim))
     return PoissonDelta(stat=stat, key=key, states=states,
-                        est_state=stat.init_state(dim), B=B, n=0, step=0,
+                        est_state=est_state, B=B, n=0, step=0,
                         backend=backend, mesh=mesh, data_axis=data_axis)
+
+
+@partial(jax.jit, static_argnames=("stat", "B", "dim"))
+def _pd_init_jit(params, stat, B, dim):
+    """B empty resample states and the estimate's, as one cached program
+    (built eagerly, each of their arrays would be a dispatch of its own)."""
+    stat = bind_params(stat, params)
+    return (jax.vmap(lambda _: stat.init_state(dim))(jnp.arange(B)),
+            stat.init_state(dim))
 
 
 @partial(jax.jit, static_argnames=("stat", "B", "backend", "mesh",
@@ -115,37 +126,85 @@ def poisson_delta_extend(pd: PoissonDelta, new_values: jax.Array
                                n=pd.n + dn, step=pd.step + 1)
 
 
+@partial(jax.jit, static_argnames=("stat",))
+def _pd_result_jit(states, params, p, stat):
+    """The session's result step as one program, cached per statistic
+    spec and the states' shapes (B among them); ``p`` is traced, so every
+    round reuses it.  Finalizes and corrects the resample states and
+    computes the report's numbers on the device."""
+    trace.count(trace.RESULT_TRACES)           # runs only while JAX traces
+    stat = bind_params(stat, params)
+    thetas = stat.correct(jax.vmap(stat.finalize)(states), p)
+    numbers = accuracy.report_numbers(
+        thetas, num_groups=getattr(stat, "num_groups", None))
+    return thetas, numbers, accuracy.report_scalars(numbers)
+
+
+def _one_device(tree):
+    """Each leaf replicated over several devices (a mesh fold's psummed
+    states) as its copy on one of them, without a transfer: what reads the
+    states after the fold then runs as a one-device program, not as one
+    program a chip."""
+    def one(a):
+        if len(a.sharding.device_set) > 1 and a.sharding.is_fully_replicated:
+            return a.addressable_data(0)
+        return a
+    return jax.tree_util.tree_map(one, tree)
+
+
+def _result_step(pd: PoissonDelta, p: float):
+    """Corrected thetas and their report from one compiled step, the
+    report's scalar gates read on the host in one ``host_sync``."""
+    spec, params = split_params(pd.stat)
+    states = pd.states if pd.mesh is None else _one_device(pd.states)
+    trace.count(trace.RESULT_STEPS)
+    thetas, numbers, scalars = _pd_result_jit(states, params,
+                                              np.float32(p), spec)
+    return thetas, accuracy.report_from_numbers(
+        numbers, trace.to_host(scalars),
+        getattr(pd.stat, "num_groups", None))
+
+
+def poisson_delta_report(pd: PoissonDelta, p: float = 1.0):
+    """The accuracy report of a delta run's resamples alone (SSABE's
+    n-steps read its c_v and need no estimate)."""
+    return _result_step(pd, p)[1]
+
+
 def poisson_delta_result(pd: PoissonDelta, estimate: Any = None,
                          p: float = 1.0,
                          p_keys: Optional[np.ndarray] = None
                          ) -> BootstrapResult:
     """Finalize a delta run into a ``BootstrapResult``.
 
-    ``p`` is the whole-table sampled fraction for ``correct``.  For a
-    keyed statistic under STRATIFIED sampling, pass ``p_keys`` (per-key
-    sampled fractions, length ``num_groups``) instead: each key's thetas
-    and estimate are corrected by that key's own inclusion probability
+    ``p`` is the whole-table sampled fraction for ``correct``.  The thetas
+    and the report come from one compiled step (``_result_step``); the
+    estimate — the maintained one, or ``estimate`` if given — is finalized
+    and corrected eagerly, so that the answer is what ``stat.finalize``
+    gives as the code stands, never a program built before.  For a keyed
+    statistic under STRATIFIED sampling, pass ``p_keys`` (per-key sampled
+    fractions, length ``num_groups``) instead: each key's thetas and
+    estimate are corrected eagerly by that key's own inclusion probability
     (``GroupedStatistic.correct_per_key``), and the fractions are surfaced
     on the resulting ``KeyedAccuracyReport.p_keys``."""
     num_groups = getattr(pd.stat, "num_groups", None)
-    raw_thetas = jax.vmap(pd.stat.finalize)(pd.states)
+    if p_keys is not None and num_groups is None:
+        raise ValueError("p_keys needs a keyed statistic "
+                         "(GroupedStatistic)")
     if estimate is None:
-        estimate = pd.stat.finalize(pd.est_state)
+        estimate = pd.stat.finalize(pd.est_state if pd.mesh is None
+                                    else _one_device(pd.est_state))
     if p_keys is not None:
-        if num_groups is None:
-            raise ValueError("p_keys needs a keyed statistic "
-                             "(GroupedStatistic)")
-        thetas = pd.stat.correct_per_key(raw_thetas, p_keys, key_axis=1)
+        thetas = pd.stat.correct_per_key(
+            jax.vmap(pd.stat.finalize)(pd.states), p_keys, key_axis=1)
         estimate = pd.stat.correct_per_key(estimate, p_keys, key_axis=0)
+        report = accuracy.report_for(thetas, num_groups=num_groups,
+                                     p_keys=p_keys)
     else:
-        thetas = pd.stat.correct(raw_thetas, p)
+        thetas, report = _result_step(pd, p)
         estimate = pd.stat.correct(estimate, p)
-    return BootstrapResult(
-        estimate=estimate, thetas=thetas,
-        report=accuracy.report_for(thetas, num_groups=num_groups,
-                                   p_keys=p_keys),
-        B=pd.B, n=pd.n,
-    )
+    return BootstrapResult(estimate=estimate, thetas=thetas, report=report,
+                           B=pd.B, n=pd.n)
 
 
 # ============================================================================

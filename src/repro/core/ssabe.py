@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+from functools import partial
 from typing import List, Tuple
 
 import jax
@@ -25,27 +26,14 @@ import numpy as np
 
 from repro import trace
 from repro.core import accuracy
-from repro.core.bootstrap import (bootstrap_thetas, fused_resample_states,
-                                  mesh_fold, seed_from_key,
-                                  sharded_fused_states, weights_for)
-from repro.core.delta import poisson_delta_extend, poisson_delta_init, \
-    poisson_delta_result
-from repro.core.reduce_api import Statistic, _as_2d
-
-
-def _cv_of(thetas, num_groups=None) -> float:
-    """c_v of a theta distribution — for a StatisticGroup's tuple of
-    per-member thetas this is the WORST member, so phase A/B converge only
-    once every member of the group is stable (the group's AES contract).
-    With ``num_groups`` (a GroupedStatistic's (B, G, ...) thetas) it is the
-    WORST KEY, the per-key analogue of the same contract."""
-    def cv(t):
-        return trace.to_host(accuracy.coefficient_of_variation(t), float)
-    if isinstance(thetas, (tuple, list)):
-        return max(cv(t) for t in thetas)
-    if num_groups is not None:
-        return max(cv(thetas[:, g]) for g in range(int(num_groups)))
-    return cv(thetas)
+from repro.core.bootstrap import (_resample_states_shape, bootstrap_thetas,
+                                  fused_resample_states, mesh_fold,
+                                  seed_from_key, sharded_fused_states,
+                                  weights_for)
+from repro.core.delta import (poisson_delta_extend, poisson_delta_init,
+                              poisson_delta_report)
+from repro.core.reduce_api import (Statistic, _as_2d, bind_params,
+                                   split_params)
 
 
 @dataclasses.dataclass
@@ -60,6 +48,53 @@ class SSABEResult:
     n_theory: int               # CLT prediction (for fig8)
 
 
+def _candidates(B_min: int, B_max: int) -> Tuple[int, ...]:
+    """The geometric ladder of B candidates: consecutive integers differ by
+    O(1/B) by construction (nested prefixes), which would stop at B≈3 for
+    any tau; doubling candidates make the |Δc_v| < τ test measure real
+    convergence of the bootstrap variance estimate (paper Fig 2a flattens
+    near B≈30)."""
+    candidates = []
+    b = max(2, B_min)
+    while b < B_max:
+        candidates.append(b)
+        b *= 2
+    candidates.append(B_max)
+    return tuple(candidates)
+
+
+@partial(jax.jit, static_argnames=("stat", "B_max", "candidates", "engine",
+                                   "backend", "mesh", "data_axis"))
+def _ladder_jit(x, key, params, stat, B_max, candidates, engine, backend,
+                mesh=None, data_axis="data"):
+    """Phase A's ladder as one program: thetas for all B_max resamples and
+    the worst-member (or worst-key) c_v of every candidate prefix."""
+    trace.count(trace.RESULT_TRACES)           # runs only while JAX traces
+    stat = bind_params(stat, params)
+    if backend == "fused_rng":
+        # matrix-free: thetas for all B_max resamples without the (B_max, n)
+        # weight matrix (every built-in statistic has a
+        # fused_poisson_states path — moments, KMeansStep, Quantile; custom
+        # ones materialize the same implicit weights).  With a mesh the
+        # pilot shards over the data axis and only the states psum.
+        if mesh is not None:
+            states = sharded_fused_states(stat, seed_from_key(key), x, B_max,
+                                          mesh=mesh, data_axis=data_axis)
+        else:
+            states = fused_resample_states(stat, seed_from_key(key), x,
+                                           B_max)
+        thetas = jax.vmap(stat.finalize)(states)
+    else:
+        # draw the maximal weight matrix once; prefixes give nested B
+        thetas = bootstrap_thetas(x, stat,
+                                  weights_for(engine, key, B_max, x.shape[0]))
+    num_groups = getattr(stat, "num_groups", None)
+    return jnp.stack([
+        accuracy.worst_cv(jax.tree_util.tree_map(lambda t: t[:B], thetas),
+                          num_groups)
+        for B in candidates])
+
+
 def estimate_B(values: jax.Array, stat: Statistic, tau: float,
                key: jax.Array, engine: str = "poisson",
                B_min: int = 2, B_max: int | None = None,
@@ -72,7 +107,13 @@ def estimate_B(values: jax.Array, stat: Statistic, tau: float,
 
     With ``backend="fused_rng"`` the nested-prefix property is even
     structural: implicit weights are keyed per (resample-tile, item-tile),
-    so row b's weights are independent of B_max entirely."""
+    so row b's weights are independent of B_max entirely.
+
+    The c_v of a StatisticGroup is its WORST member's, so the ladder stops
+    only once every member is stable (the group's AES contract); of a
+    GroupedStatistic its WORST KEY's.  One compiled step (``_ladder_jit``)
+    computes every candidate's c_v, and the host reads them in one
+    ``host_sync`` and applies the stop rule."""
     if backend == "fused_rng" and engine != "poisson":
         raise ValueError("backend='fused_rng' requires the poisson engine "
                          "(in-kernel RNG draws iid Poisson(1) weights)")
@@ -83,46 +124,19 @@ def estimate_B(values: jax.Array, stat: Statistic, tau: float,
         B_max = max(B_min + 1, int(math.ceil(1.0 / tau)))
     x = _as_2d(values)
     n, dim = x.shape
-
-    if backend == "fused_rng" and engine == "poisson":
-        # matrix-free: thetas for all B_max resamples without the (B_max, n)
-        # weight matrix (every built-in statistic has a
-        # fused_poisson_states path — moments, KMeansStep, Quantile; custom
-        # ones materialize the same implicit weights); prefixes of thetas
-        # give nested B as before.  With a mesh the pilot shards over the
-        # data axis and only the states psum.
-        if mesh is not None:
-            with mesh_fold(mesh, data_axis, n) as psummed:
-                states = sharded_fused_states(stat, seed_from_key(key), x,
-                                              B_max, mesh=mesh,
-                                              data_axis=data_axis)
-                psummed(states)
-        else:
-            states = fused_resample_states(stat, seed_from_key(key), x,
-                                           B_max)
-        thetas_full = jax.vmap(stat.finalize)(states)
-    else:
-        # draw the maximal weight matrix once; prefixes give nested B
-        w_full = weights_for(engine, key, B_max, n)
-        thetas_full = bootstrap_thetas(x, stat, w_full)
-
-    # geometric candidate ladder: consecutive integers differ by O(1/B) by
-    # construction (nested prefixes), which would stop at B≈3 for any tau;
-    # doubling candidates make the |Δc_v| < τ test measure real convergence
-    # of the bootstrap variance estimate (paper Fig 2a flattens near B≈30).
-    candidates = []
-    b = max(2, B_min)
-    while b < B_max:
-        candidates.append(b)
-        b *= 2
-    candidates.append(B_max)
+    candidates = _candidates(B_min, B_max)
+    spec, params = split_params(stat)
+    trace.count(trace.RESULT_STEPS)
+    with mesh_fold(mesh, data_axis, n) as psummed:
+        cvs = _ladder_jit(x, key, params, spec, int(B_max), candidates,
+                          engine, backend, mesh, data_axis)
+        if mesh is not None:        # the states psummed inside the program
+            psummed(_resample_states_shape(spec, int(B_max), dim))
 
     history: List[Tuple[int, float]] = []
     prev_cv = None
     chosen = B_max
-    for B in candidates:
-        cv = _cv_of(jax.tree_util.tree_map(lambda t: t[:B], thetas_full),
-                    num_groups=getattr(stat, "num_groups", None))
+    for B, cv in zip(candidates, trace.to_host(cvs).tolist()):
         history.append((B, cv))
         if prev_cv is not None and abs(cv - prev_cv) < tau:
             chosen = B
@@ -171,8 +185,7 @@ def estimate_n(values: jax.Array, stat: Statistic, sigma: float, B: int,
         ni = max(2, n // (2 ** (l - i)))
         pd = poisson_delta_extend(pd, x[prev:ni])
         prev = ni
-        res = poisson_delta_result(pd, estimate=stat(x[:ni]))
-        history.append((ni, res.cv))
+        history.append((ni, poisson_delta_report(pd).cv))
 
     ns = np.array([h[0] for h in history])
     cvs = np.array([h[1] for h in history])

@@ -20,6 +20,11 @@ On a mesh, ``mesh_folds`` counts the sharded folds the host dispatches
 (each inside an ``earl.mesh.fold`` span), ``mesh_psum_bytes`` the bytes of
 the state pytrees they psum, and ``mesh_traces`` the times JAX traces a
 sharded fold's body, which a warm fold does not.
+``result_steps`` counts the session's compiled result steps the host
+dispatches: SSABE's B̂ ladder, each of its ``l`` n-steps and each σ round
+(``poisson_delta_result``), 1 + l + rounds a session, each read back in one
+``host_sync``; ``result_traces`` counts the times JAX traces their bodies,
+which a warm query does not.
 """
 from __future__ import annotations
 
@@ -34,6 +39,8 @@ HOST_SYNC = "host_sync"
 MESH_FOLDS = "mesh_folds"
 MESH_TRACES = "mesh_traces"
 MESH_PSUM_BYTES = "mesh_psum_bytes"
+RESULT_STEPS = "result_steps"
+RESULT_TRACES = "result_traces"
 
 _lock = threading.Lock()
 _counts: Dict[str, int] = {}

@@ -24,6 +24,7 @@ import os
 import subprocess
 import sys
 
+import numpy as np
 import pytest
 
 _SRC = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -165,6 +166,37 @@ for step, piece in enumerate((x[:2000], x[2000:])):
 out["bitwise_delta"] = leaves_equal(pd.states, ref)
 out["delta_cv"] = poisson_delta_result(pd).cv
 
+# --- the session's compiled result step and B-hat ladder over the mesh --
+# the same answers as on the nshards= oracle's states, on one device
+import dataclasses, importlib
+from repro.core import accuracy
+ssabe_mod = importlib.import_module("repro.core.ssabe")
+grp = StatisticGroup((Mean(), Quantile(0.5, nbins=256, lo=0.0, hi=20.0),
+                      Var()))
+pg = poisson_delta_init(grp, 16, 2, key, backend="fused_rng", mesh=mesh)
+pg = poisson_delta_extend(pg, x)
+on_mesh = poisson_delta_result(pg, p=0.5)
+oracle = poisson_delta_result(dataclasses.replace(
+    pg, mesh=None, states=sharded_fused_states(
+        grp, seed_from_key(key), jnp.asarray(x), 16, nshards=8)), p=0.5)
+out["result_step_mesh_bitwise"] = leaves_equal(on_mesh.thetas,
+                                               oracle.thetas)
+out["result_step_mesh_cvs"] = [on_mesh.report.cvs, oracle.report.cvs]
+B_hat, hist = ssabe_mod.estimate_B(x, grp, 0.01, key, backend="fused_rng",
+                                   mesh=mesh)
+th = jax.vmap(grp.finalize)(sharded_fused_states(
+    grp, seed_from_key(key), jnp.asarray(x), 100, nshards=8))
+out["ladder_mesh"] = [B_hat, [list(h) for h in hist]]
+oracle_hist, prev, oracle_B = [], None, 100
+for b in ssabe_mod._candidates(2, 100):
+    cv = max(float(accuracy.coefficient_of_variation(t[:b])) for t in th)
+    oracle_hist.append([b, cv])
+    if prev is not None and abs(cv - prev) < 0.01:
+        oracle_B = b
+        break
+    prev = cv
+out["ladder_oracle"] = [oracle_B, oracle_hist]
+
 # --- EarlSession end-to-end over the mesh -------------------------------
 class _Sampler:
     def __init__(self, data):
@@ -259,6 +291,22 @@ def test_sharded_quantile_sketch(subproc_result):
 def test_sharded_delta_bitwise_and_sane(subproc_result):
     assert subproc_result["bitwise_delta"]
     assert 0 < subproc_result["delta_cv"] < 0.1
+
+
+def test_compiled_result_step_and_ladder_under_mesh(subproc_result):
+    """The session's compiled result step on the psummed states, and its
+    B̂ ladder folded over the mesh inside one program, give the answers of
+    the ``nshards=`` oracle's states."""
+    r = subproc_result
+    assert r["result_step_mesh_bitwise"]
+    np.testing.assert_allclose(*r["result_step_mesh_cvs"], rtol=1e-6)
+    (B_hat, hist), (oracle_B, oracle_hist) = (r["ladder_mesh"],
+                                              r["ladder_oracle"])
+    assert B_hat == oracle_B
+    assert [b for b, _ in hist] == [b for b, _ in oracle_hist]
+    np.testing.assert_allclose([c for _, c in hist],
+                               [c for _, c in oracle_hist],
+                               rtol=1e-5, atol=1e-6)
 
 
 def test_sharded_session_end_to_end(subproc_result):

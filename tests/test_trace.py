@@ -174,13 +174,17 @@ def test_host_syncs_follow_ladder_l_and_rounds(stat, sigma, backend,
     out = sess.run(jax.random.PRNGKey(0))
     after = trace.counts()
     assert not out.fell_back
-    ladder = len(out.ssabe.cv_history_B)
-    # c_v per member per ladder step; three numbers per member in every
-    # report (one per SSABE n-step, one per round); the pilot read on the
-    # host, its CLT size, and the run fingerprint's seed
-    expected = (members * ladder + 3 * members * sess.l + 2
-                + 3 * members * out.iterations + 1)
+    # every member's c_v comes back with the round's one read
+    assert len(out.history[-1].get("member_cvs", (out.cv,))) == members
+    # one read of every ladder candidate's c_v; one read of each result
+    # step's scalars, whatever the members (one per SSABE n-step, one per
+    # round); the pilot read on the host, its CLT size, and the run
+    # fingerprint's seed
+    expected = 1 + sess.l + 2 + out.iterations + 1
     assert after["host_sync"] - before.get("host_sync", 0) == expected
+    # compiled steps: the ladder, each n-step and each round
+    assert (after["result_steps"] - before.get("result_steps", 0)
+            == 1 + sess.l + out.iterations)
     assert after["session_run"] - before.get("session_run", 0) == 1
 
 
