@@ -520,8 +520,8 @@ def run_stream(smoke: bool = False) -> None:
 
     import numpy as np
 
-    from repro.core.bootstrap import (bootstrap_chunked, offset_seed,
-                                      seed_from_key)
+    from repro.core import fold
+    from repro.core.bootstrap import bootstrap_chunked, seed_from_key
     from repro.core.streaming import bootstrap_streaming
     from repro.data.store import ShardedStore
 
@@ -539,19 +539,14 @@ def run_stream(smoke: bool = False) -> None:
         nn, dim = xd.shape
         xp = jnp.pad(xd, ((0, (-nn) % chunk), (0, 0)))
         xc = xp.reshape(-1, chunk, dim)
-        init = jax.vmap(lambda _: stat.init_state(dim))(jnp.arange(B))
 
         def body(carry, inp):
-            states, est = carry
             i, xi = inp
             n_valid = jnp.minimum(chunk, nn - i * chunk)
-            vi = (jnp.arange(chunk) < n_valid).astype(jnp.float32)
-            est = stat.update(est, xi, vi)
-            delta = fused_resample_states(stat, offset_seed(base_seed, i),
-                                          xi, B, n_valid=n_valid)
-            return (jax.vmap(stat.merge)(states, delta), est), None
+            return fold.fold_rows(stat, *carry, xi, base_seed, i, B,
+                                  n_valid=n_valid), None
 
-        return jax.lax.scan(body, (init, stat.init_state(dim)),
+        return jax.lax.scan(body, fold.empty_carry(stat, B, dim),
                             (jnp.arange(xc.shape[0]), xc))[0]
 
     def serial():

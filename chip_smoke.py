@@ -285,14 +285,14 @@ def phase_stream(rec, store, exact, B=256):
     import jax.numpy as jnp
 
     from repro.core import Mean, bootstrap_streaming
+    from repro.core.fold import empty_carry
     from repro.core.reduce_api import split_params
     from repro.core.streaming import _stream_chunk_jit
     facts = {"rows": int(store.N), "B": B, "chunk": CHUNK}
     with rec.phase("d_stream", facts):
         stat = Mean()
         spec, params = split_params(stat)
-        states = jax.vmap(lambda _: stat.init_state(1))(jnp.arange(B))
-        _kernel_compiled(_stream_chunk_jit, states, stat.init_state(1),
+        _kernel_compiled(_stream_chunk_jit, *empty_carry(stat, B, 1),
                          jax.ShapeDtypeStruct((CHUNK, 1), jnp.float32),
                          jax.ShapeDtypeStruct((CHUNK,), jnp.float32),
                          jnp.int32(0), jnp.int32(0), params, spec, B)

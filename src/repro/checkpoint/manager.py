@@ -59,6 +59,14 @@ def _unflatten_into(template: Any, flat: Dict[str, np.ndarray]) -> Any:
     return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
+def cursor(kind: str, fingerprint: str, **fields) -> Dict[str, Any]:
+    """The ``extra`` of a resumable run's snapshot, for ``save``: the
+    cursor with the run's ``kind``, its ``fingerprint`` and its own
+    JSON-plain ``fields``, which ``CheckpointManager.restore_cursor`` checks
+    and hands back."""
+    return {"cursor": {"kind": kind, "fingerprint": fingerprint, **fields}}
+
+
 class CheckpointManager:
     def __init__(self, root: str, keep_last: int = 3,
                  async_save: bool = True):
@@ -86,6 +94,21 @@ class CheckpointManager:
         """
         return cls(os.path.join(root, f"run_{fingerprint[:16]}"),
                    keep_last=keep_last, async_save=async_save)
+
+    @classmethod
+    def open(cls, checkpoint, scope: Optional[str] = None,
+             resume: bool = False) -> Optional["CheckpointManager"]:
+        """A ``checkpoint=`` argument as a manager: a root path opens one
+        with async saves (scoped by ``for_run`` to a run fingerprint
+        ``scope``, if given); a manager or None passes through."""
+        if resume and checkpoint is None:
+            raise ValueError("resume=True needs checkpoint= (where would "
+                             "the cursor come from?)")
+        if not isinstance(checkpoint, (str, os.PathLike)):
+            return checkpoint
+        if scope is not None:
+            return cls.for_run(checkpoint, scope)
+        return cls(checkpoint, async_save=True)
 
     @staticmethod
     def _pid_alive(pid_s: str) -> bool:
@@ -270,6 +293,34 @@ class CheckpointManager:
         else:
             state = jax.tree_util.tree_map(jax.numpy.asarray, state)
         return state, meta["extra"]
+
+    def restore_cursor(self, kind: str, fingerprint: str, template: Any,
+                       what: str, binds: str) -> tuple[Any, Dict[str, Any]]:
+        """The latest ``cursor`` snapshot of a ``kind`` run, as
+        ``(carry, cursor)``.
+
+        The cursor is checked before any array is read, so a checkpoint of
+        another kind (``what`` names this one) or of another run (whose
+        fingerprint differs in ``binds``) fails with its own message, not a
+        shape mismatch.  ``template``: the carry's shapes, or a function of
+        the cursor that gives them.  A cursor without a kind is a stream
+        pass's, as the stream pass wrote them before cursors had kinds.
+        """
+        cur = self.meta().get("cursor")
+        if cur is None or cur.get("kind", "stream") != kind:
+            raise ValueError(f"checkpoint under {self.root} has no {what} "
+                             f"cursor — not a {what} checkpoint")
+        if cur["fingerprint"] != fingerprint:
+            raise ValueError(
+                "checkpoint fingerprint mismatch: the snapshot was taken "
+                f"under a different {binds} — resuming it would silently "
+                "produce a different estimator "
+                f"(checkpoint {cur['fingerprint'][:12]}…, "
+                f"run {fingerprint[:12]}…)")
+        if callable(template):
+            template = template(cur)
+        carry, _ = self.restore(template)
+        return carry, cur
 
     # -- gc ---------------------------------------------------------------
     def _gc(self) -> None:

@@ -62,6 +62,7 @@ import jax.numpy as jnp
 
 from repro import trace
 from repro.core import accuracy
+from repro.core import fold          # which imports this module in turn
 from repro.core.reduce_api import (Statistic, _as_2d, bind_params,
                                    split_params)
 
@@ -173,33 +174,25 @@ def _shard_local_states(stat: Statistic, base_seed, x_local: jax.Array,
     single-read estimate (no second pass over the data).
     """
     n_local, dim = x_local.shape
+    est = stat.init_state(dim) if with_estimate else None
     if chunk is None:
-        seed = offset_seed(base_seed, step * nshards + shard_idx)
-        states = fused_resample_states(stat, seed, x_local, B,
-                                       n_valid=n_valid_local)
-        if not with_estimate:
-            return states
-        vi = (jnp.arange(n_local) < n_valid_local).astype(jnp.float32)
-        est = stat.update(stat.init_state(dim), x_local, vi)
-        return states, est
+        states, est = fold.fold_rows(stat, None, est, x_local, base_seed,
+                                     step * nshards + shard_idx, B,
+                                     n_valid=n_valid_local)
+        return (states, est) if with_estimate else states
     pad = (-n_local) % chunk
     xp = jnp.pad(x_local, ((0, pad), (0, 0)))
     nchunks = xp.shape[0] // chunk
     xc = xp.reshape(nchunks, chunk, dim)
-    init = jax.vmap(lambda _: stat.init_state(dim))(jnp.arange(B))
-    init_est = stat.init_state(dim)
 
     def body(carry, c):
-        states, est = carry
         nv = jnp.clip(n_valid_local - c * chunk, 0, chunk)
-        seed = offset_seed(base_seed, (step + c) * nshards + shard_idx)
-        delta = fused_resample_states(stat, seed, xc[c], B, n_valid=nv)
-        if with_estimate:
-            vi = (jnp.arange(chunk) < nv).astype(jnp.float32)
-            est = stat.update(est, xc[c], vi)
-        return (jax.vmap(stat.merge)(states, delta), est), None
+        return fold.fold_rows(stat, *carry, xc[c], base_seed,
+                              (step + c) * nshards + shard_idx, B,
+                              n_valid=nv), None
 
-    (states, est), _ = jax.lax.scan(body, (init, init_est),
+    (states, est), _ = jax.lax.scan(body,
+                                    (fold.empty_states(stat, B, dim), est),
                                     jnp.arange(nchunks, dtype=jnp.int32))
     return (states, est) if with_estimate else states
 
@@ -334,8 +327,7 @@ def mesh_fold(mesh, data_axis: str, n: int):
 @lru_cache(maxsize=None)
 def _resample_states_shape(stat: Statistic, B: int, dim: int):
     """Shapes of ``B`` resample states of ``stat`` over ``dim`` columns."""
-    return jax.eval_shape(
-        lambda: jax.vmap(lambda _: stat.init_state(dim))(jnp.arange(B)))
+    return jax.eval_shape(lambda: fold.empty_states(stat, B, dim))
 
 
 def multinomial_counts(key: jax.Array, B: int, n: int,
@@ -519,8 +511,6 @@ def bootstrap_chunked(values: jax.Array, stat: Statistic, B: int,
         nchunks = xp.shape[0] // chunk
         xc = xp.reshape(nchunks, chunk, dim)
 
-        init = jax.vmap(lambda _: stat.init_state(dim))(jnp.arange(B))
-        init_est = stat.init_state(dim)
         base_seed = seed_from_key(key)  # one base; chunks offset by counter
 
         def body(carry, inp):
@@ -529,18 +519,17 @@ def bootstrap_chunked(values: jax.Array, stat: Statistic, B: int,
             n_valid = jnp.minimum(chunk, n - i * chunk)  # last-chunk suffix
             # unweighted estimate rides the SAME pass over xi (the old
             # ``stat(values)`` was a second full read of the sample).
+            if backend == "fused_rng":
+                return fold.fold_rows(stat, states, est, xi, base_seed, i, B,
+                                      n_valid=n_valid), None
             vi = (jnp.arange(chunk) < n_valid).astype(jnp.float32)
             est = stat.update(est, xi, vi)
-            if backend == "fused_rng":
-                delta = fused_resample_states(
-                    stat, offset_seed(base_seed, i), xi, B, n_valid=n_valid)
-                return (jax.vmap(stat.merge)(states, delta), est), None
             w = poisson_weights(jax.random.fold_in(key, i), B, chunk) \
                 * vi[None, :]
             new = jax.vmap(lambda s, wr: stat.update(s, xi, wr))(states, w)
             return (new, est), None
 
-        (states, est), _ = jax.lax.scan(body, (init, init_est),
+        (states, est), _ = jax.lax.scan(body, fold.empty_carry(stat, B, dim),
                                         (jnp.arange(nchunks), xc))
     thetas = jax.vmap(stat.finalize)(states)
     thetas = stat.correct(thetas, p)

@@ -32,10 +32,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import trace
-from repro.core import accuracy
-from repro.core.bootstrap import (BootstrapResult, fused_resample_states,
-                                  mesh_fold, offset_seed, poisson_weights,
-                                  seed_from_key, sharded_fused_states)
+from repro.core import accuracy, fold
+from repro.core.bootstrap import (BootstrapResult, mesh_fold,
+                                  poisson_weights, seed_from_key,
+                                  sharded_fused_states)
 from repro.core.reduce_api import (Statistic, StatisticGroup, _as_2d,
                                    bind_params, split_params)
 
@@ -77,9 +77,7 @@ def poisson_delta_init(stat: Statistic, B: int, dim: int, key: jax.Array,
 def _pd_init_jit(params, stat, B, dim):
     """B empty resample states and the estimate's, as one cached program
     (built eagerly, each of their arrays would be a dispatch of its own)."""
-    stat = bind_params(stat, params)
-    return (jax.vmap(lambda _: stat.init_state(dim))(jnp.arange(B)),
-            stat.init_state(dim))
+    return fold.empty_carry(bind_params(stat, params), B, dim)
 
 
 @partial(jax.jit, static_argnames=("stat", "B", "backend", "mesh",
@@ -99,13 +97,15 @@ def _pd_extend_jit(states, est_state, key, step, x, params, stat, B,
             delta_states = sharded_fused_states(
                 stat, seed_from_key(key), x, B, mesh=mesh,
                 data_axis=data_axis, step=step)
+            new_states = jax.vmap(stat.merge)(states, delta_states)
         else:
-            delta_states = fused_resample_states(
-                stat, offset_seed(seed_from_key(key), step), x, B)
-        new_states = jax.vmap(stat.merge)(states, delta_states)
+            new_states, _ = fold.fold_rows(stat, states, None, x,
+                                           seed_from_key(key), step, B)
     else:
         w = poisson_weights(jax.random.fold_in(key, step), B, x.shape[0])
         new_states = jax.vmap(lambda s, wr: stat.update(s, x, wr))(states, w)
+    # every backend's estimate, after the resample fold: handed to
+    # fold_rows, its update would come first in the program
     new_est = stat.update(est_state, x)
     return new_states, new_est
 

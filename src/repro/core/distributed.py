@@ -23,9 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core import accuracy
-from repro.core.bootstrap import (BootstrapResult, fused_resample_states,
-                                  offset_seed, seed_from_key)
+from repro.core import accuracy, fold
+from repro.core.bootstrap import BootstrapResult, seed_from_key
 from repro.core.reduce_api import Statistic, _as_2d
 
 
@@ -74,9 +73,9 @@ def build_bootstrap_step(mesh: Mesh, stat: Statistic, B: int,
             idx = idx * mesh.shape[a] + jax.lax.axis_index(a)
         n_local, dim = values.shape
         if backend == "fused_rng":
-            states = fused_resample_states(
-                stat, offset_seed(seed_from_key(key), idx), values, B,
-                valid_mask=mask)
+            states, _ = fold.fold_rows(stat, None, None, values,
+                                       seed_from_key(key), idx, B,
+                                       valid_mask=mask)
         else:
             w = _poisson_for_shard(key, idx, B, n_local) * mask[None, :]
 

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import trace
+from repro.checkpoint.manager import CheckpointManager, cursor
 from repro.core import ssabe as ssabe_mod
 from repro.core.bootstrap import BootstrapResult, seed_from_key
 from repro.core.delta import (PoissonDelta, poisson_delta_extend,
@@ -162,13 +163,7 @@ class EarlSession:
         N = self.sampler.N
         history: List[dict] = []
 
-        mgr = self.checkpoint
-        if isinstance(mgr, str):
-            from repro.checkpoint.manager import CheckpointManager
-            mgr = CheckpointManager(mgr, async_save=True)
-        if resume and mgr is None:
-            raise ValueError("resume=True needs checkpoint= (where would "
-                             "the cursor come from?)")
+        mgr = CheckpointManager.open(self.checkpoint, resume=resume)
 
         # ---- pilot + SSABE (local mode) --------------------------------
         n_pilot = min(N, self.max_pilot,
@@ -197,24 +192,23 @@ class EarlSession:
                              dim)
         n_have = 0
         iterations = 0
+
+        def early(res):
+            return EarlyResult(
+                result=res.estimate, cv=res.cv, ci_lo=res.report.ci_lo,
+                ci_hi=res.report.ci_hi, n_used=n_have, N=N,
+                fraction=n_have / N, B=B, iterations=iterations,
+                fell_back=False, history=history,
+                wall_time_s=time.perf_counter() - t0, ssabe=est,
+                reports=getattr(res.report, "members", None))
+
         if resume:
             # pilot + SSABE were just recomputed deterministically from the
             # same key, so B/n_target/est match the original run; only the
             # delta-maintained carry and the cursor come from disk.
-            cur = mgr.meta().get("cursor")
-            if cur is None or cur.get("kind") != "session":
-                raise ValueError(
-                    f"checkpoint under {mgr.root} has no EarlSession "
-                    "cursor — not an EarlSession checkpoint")
-            if cur["fingerprint"] != fp:
-                raise ValueError(
-                    "checkpoint fingerprint mismatch: the snapshot was "
-                    "taken under a different (statistic, B, key, sampler) "
-                    "— resuming it would silently produce a different "
-                    f"estimator (checkpoint {cur['fingerprint'][:12]}…, "
-                    f"run {fp[:12]}…)")
-            template = jax.eval_shape(lambda: (pd.states, pd.est_state))
-            (states, est_state), _ = mgr.restore(template)
+            (states, est_state), cur = mgr.restore_cursor(
+                "session", fp, (pd.states, pd.est_state), "EarlSession",
+                "(statistic, B, key, sampler)")
             pd = dataclasses.replace(pd, states=states, est_state=est_state,
                                      n=int(cur["n_have"]),
                                      step=int(cur["step"]))
@@ -232,14 +226,7 @@ class EarlSession:
                 res = poisson_delta_result(pd, p=p,
                                            p_keys=self._p_keys(n_have))
             if res.cv <= self.sigma or n_have >= self.max_fraction * N:
-                return EarlyResult(
-                    result=res.estimate, cv=res.cv,
-                    ci_lo=res.report.ci_lo, ci_hi=res.report.ci_hi,
-                    n_used=n_have, N=N, fraction=p, B=B,
-                    iterations=iterations, fell_back=False,
-                    history=history,
-                    wall_time_s=time.perf_counter() - t0, ssabe=est,
-                    reports=getattr(res.report, "members", None))
+                return early(res)
         while True:
             iterations += 1
             with trace.span("session.round", round=iterations):
@@ -273,9 +260,9 @@ class EarlSession:
                     # the cursor rides meta.json, so history must be
                     # JSON-plain
                     mgr.save(iterations, (pd.states, pd.est_state),
-                             extra={"cursor": dict(
-                                 kind="session", fingerprint=fp,
-                                 n_have=int(n_have), step=int(pd.step),
+                             extra=cursor(
+                                 "session", fp, n_have=int(n_have),
+                                 step=int(pd.step),
                                  iterations=int(iterations),
                                  n_target_next=int(min(
                                      N, int(n_have * self.growth))),
@@ -283,18 +270,11 @@ class EarlSession:
                                      {**e,
                                       "member_cvs": list(e["member_cvs"])}
                                      if "member_cvs" in e else e
-                                     for e in history])})
+                                     for e in history]))
             if res.cv <= self.sigma or n_have >= self.max_fraction * N:
                 if mgr is not None:
                     mgr.wait()          # durable before we report success
-                return EarlyResult(
-                    result=res.estimate, cv=res.cv,
-                    ci_lo=res.report.ci_lo, ci_hi=res.report.ci_hi,
-                    n_used=n_have, N=N, fraction=p, B=B,
-                    iterations=iterations, fell_back=False,
-                    history=history,
-                    wall_time_s=time.perf_counter() - t0, ssabe=est,
-                    reports=member_reports)
+                return early(res)
             if n_have >= N:
                 if mgr is not None:
                     mgr.wait()

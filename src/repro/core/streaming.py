@@ -60,7 +60,6 @@ import queue as queue_mod
 import threading
 import time
 import zlib
-from functools import partial
 from typing import Optional, Tuple
 
 import jax
@@ -68,9 +67,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import trace
-from repro.core import accuracy
-from repro.core.bootstrap import (BootstrapResult, fused_resample_states,
-                                  offset_seed, seed_from_key)
+from repro.checkpoint.manager import CheckpointManager, cursor
+from repro.core import accuracy, fold
+from repro.core.bootstrap import BootstrapResult, seed_from_key
 from repro.core.reduce_api import Statistic, bind_params, split_params
 
 
@@ -116,29 +115,9 @@ class StreamingBootstrapResult(BootstrapResult):
     stream: StreamReport = None
 
 
-@partial(jax.jit, static_argnames=("spec", "B"), donate_argnums=(0, 1))
-def _stream_chunk_jit(states, est, xi, vi, base_seed, i, params, spec, B):
-    """Fold ONE staged chunk into the running (states, est) carry.
-
-    Identical math, operand layout and seed derivation as the
-    ``bootstrap_chunked`` fused scan body (bitwise-equality contract);
-    ``vi`` is the chunk's exact 0.0/1.0 validity mask — a plain prefix
-    for the ragged tail (bit-identical to the historical n_valid path),
-    with interior holes for rows of splits lost mid-run.  ``states``/
-    ``est`` are donated so the carry is updated in place and the device
-    never holds two copies.  The point estimate's update and the resample
-    fold run under the name scopes ``earl.estimate`` and ``earl.resample``,
-    which name their operations in the compiled program's ``op_name``
-    metadata.
-    """
-    stat = bind_params(spec, params)
-    with jax.named_scope("earl.estimate"):
-        est = stat.update(est, xi, vi)
-    with jax.named_scope("earl.resample"):
-        delta = fused_resample_states(stat, offset_seed(base_seed, i), xi,
-                                      B, valid_mask=vi)
-        states = jax.vmap(stat.merge)(states, delta)
-    return states, est
+#: the per-chunk fold step.  The pass calls it through this module global,
+#: so a test can put another step in its place.
+_stream_chunk_jit = fold.fold_chunk_jit
 
 
 def _put_until(out_q, item, stop) -> bool:
@@ -342,9 +321,6 @@ def bootstrap_streaming(store, stat: Statistic, B: int, key: jax.Array,
     if checkpoint_every < 1:
         raise ValueError(
             f"checkpoint_every must be >= 1, got {checkpoint_every}")
-    if resume and checkpoint is None:
-        raise ValueError("resume=True needs checkpoint= (where would the "
-                         "cursor come from?)")
     # earl.stream.open: carry init, fingerprint, thread start and the
     # first wait, up to the return of the first chunk's dispatch
     opening = contextlib.ExitStack()
@@ -353,10 +329,7 @@ def bootstrap_streaming(store, stat: Statistic, B: int, key: jax.Array,
         head = store.splits[0]
         dim = int(np.prod(head.shape[1:])) if head.ndim > 1 else 1
 
-        mgr = checkpoint
-        if isinstance(mgr, str):
-            from repro.checkpoint.manager import CheckpointManager
-            mgr = CheckpointManager(mgr, async_save=True)
+        mgr = CheckpointManager.open(checkpoint, resume=resume)
 
         # resilient read path: policy wins over bare retry
         reader = store
@@ -379,15 +352,7 @@ def bootstrap_streaming(store, stat: Statistic, B: int, key: jax.Array,
                                                            upto_row=n_rows)
                                       if fingerprint_content else None))
 
-        # Fresh, UNALIASED device buffers for the donated carry: jnp's constant
-        # cache can hand several identical-zeros leaves the same buffer, which
-        # trips "attempt to donate the same buffer twice" on the first call.
-        def _fresh(tree):
-            return jax.tree_util.tree_map(
-                lambda a: jax.device_put(trace.to_host(a)), tree)
-        states = _fresh(
-            jax.vmap(lambda _: stat.init_state(dim))(jnp.arange(B)))
-        est = _fresh(stat.init_state(dim))
+        states, est = fold.fresh(fold.empty_carry(stat, B, dim))
 
         start_chunk = 0
         rows_done = 0
@@ -395,23 +360,10 @@ def bootstrap_streaming(store, stat: Statistic, B: int, key: jax.Array,
         prior_lost: Tuple[int, ...] = ()
         resumed_from = None
         if resume:
-            # validate the cursor BEFORE touching the arrays: a wrong-run
-            # checkpoint must fail on the fingerprint, not a shape mismatch
-            cur = mgr.meta().get("cursor")
-            if cur is None:
-                raise ValueError(
-                    f"checkpoint under {mgr.root} has no streaming cursor — "
-                    "not a bootstrap_streaming checkpoint")
-            if cur["fingerprint"] != fp:
-                raise ValueError(
-                    "checkpoint fingerprint mismatch: the snapshot was taken "
-                    "under a different (statistic, B, chunk, key, store) — "
-                    "resuming it would silently produce a different estimator "
-                    f"(checkpoint {cur['fingerprint'][:12]}…, "
-                    f"run {fp[:12]}…)")
-            template = jax.eval_shape(lambda: (states, est))
-            (states, est), _ = mgr.restore(template)
-            states, est = _fresh(states), _fresh(est)
+            (states, est), cur = mgr.restore_cursor(
+                "stream", fp, (states, est), "bootstrap_streaming",
+                "(statistic, B, chunk, key, store)")
+            states, est = fold.fresh((states, est))
             start_chunk = int(cur["next_chunk"])
             rows_done = int(cur["rows_done"])
             valid_rows = int(cur["valid_rows"])
@@ -437,11 +389,11 @@ def bootstrap_streaming(store, stat: Statistic, B: int, key: jax.Array,
             # resumed run keeps correcting by the right surviving fraction.
             lost_now = tuple(sorted(set(prior_lost)
                                     | set(getattr(reader, "lost_splits", ()))))
-            mgr.save(i, (states, est), extra={"cursor": {
-                "next_chunk": i, "rows_done": rows_done,
-                "valid_rows": valid_rows, "lost_splits": list(lost_now),
-                "fingerprint": fp, "B": int(B), "chunk": int(chunk),
-                "base_seed": seed_int, "N": int(n_rows)}})
+            mgr.save(i, (states, est), extra=cursor(
+                "stream", fp, next_chunk=i, rows_done=rows_done,
+                valid_rows=valid_rows, lost_splits=list(lost_now),
+                B=int(B), chunk=int(chunk), base_seed=seed_int,
+                N=int(n_rows)))
 
         try:
             while True:

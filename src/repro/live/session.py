@@ -44,18 +44,16 @@ asserted bitwise in tests/test_live.py):
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
 from typing import Any, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import accuracy
-from repro.core.bootstrap import (fused_resample_states, offset_seed,
-                                  seed_from_key)
-from repro.core.reduce_api import (Statistic, Window, bind_params,
-                                   split_params)
+from repro.checkpoint.manager import CheckpointManager, cursor
+from repro.core import accuracy, fold
+from repro.core.bootstrap import seed_from_key
+from repro.core.reduce_api import Statistic, Window, split_params
 from repro.core.streaming import run_fingerprint
 from repro.ft.policy import LagPolicy
 from repro.live.log import IngestLog, LogBatch
@@ -105,23 +103,6 @@ class _Pane:
     valid: int = 0
 
 
-@partial(jax.jit, static_argnames=("spec", "B"), donate_argnums=(0, 1))
-def _live_fold_jit(states, est, xb, mask, base_seed, seq, params, spec, B):
-    """Fold one batch slice into one pane's carry.
-
-    Same math and operand layout as ``core.streaming._stream_chunk_jit``
-    (the bitwise contracts ride on that): the batch's implicit Poisson(1)
-    weights come from ``offset_seed(base_seed, seq)`` — position-keyed,
-    so fold-time (resume, replay, late arrival) never changes the draw —
-    and ``mask`` is the exact 0/1 row mask (pane overlap ∩ shed ∩ valid).
-    """
-    stat = bind_params(spec, params)
-    est = stat.update(est, xb, mask)
-    delta = fused_resample_states(stat, offset_seed(base_seed, seq), xb, B,
-                                  valid_mask=mask)
-    return jax.vmap(stat.merge)(states, delta), est
-
-
 class LiveSession:
     """Standing session over an ``IngestLog`` (see module docstring).
 
@@ -152,9 +133,6 @@ class LiveSession:
         if checkpoint_every < 1:
             raise ValueError(
                 f"checkpoint_every must be >= 1, got {checkpoint_every}")
-        if resume and checkpoint is None:
-            raise ValueError("resume=True needs checkpoint= (where would "
-                             "the cursor come from?)")
         self.log = log
         self.stat = stat
         self.B = int(B)
@@ -173,14 +151,10 @@ class LiveSession:
         self.fingerprint = run_fingerprint(
             self._spec, self._params, self.B, int(self._base_seed), wsz, wsl)
 
-        mgr = checkpoint
-        if isinstance(mgr, str):
-            from repro.checkpoint.manager import CheckpointManager
-            # several standing sessions may share one root path: scope by
-            # run fingerprint so steps/GC never clobber a peer (satellite
-            # of ISSUE 9; tested in tests/test_checkpoint_ft.py)
-            mgr = CheckpointManager.for_run(mgr, self.fingerprint)
-        self.checkpoint = mgr
+        # standing sessions may share a root path: scoped by fingerprint,
+        # steps and GC never clobber a peer (tests/test_checkpoint_ft.py)
+        self.checkpoint = CheckpointManager.open(
+            checkpoint, scope=self.fingerprint, resume=resume)
         self.checkpoint_every = int(checkpoint_every)
 
         self._dim: Optional[int] = None
@@ -224,14 +198,9 @@ class LiveSession:
 
     # -- pane plumbing --------------------------------------------------
     def _init_pane(self) -> _Pane:
-        stat, dim = self.stat, self._dim
-
-        def _fresh(tree):       # unaliased buffers for the donated carry
-            return jax.tree_util.tree_map(
-                lambda a: jax.device_put(np.asarray(a)), tree)
-        states = _fresh(jax.vmap(lambda _: stat.init_state(dim))(
-            jnp.arange(self.B)))
-        return _Pane(states=states, est=_fresh(stat.init_state(dim)))
+        states, est = fold.fresh(fold.empty_carry(self.stat, self.B,
+                                                  self._dim))
+        return _Pane(states=states, est=est)
 
     def _pane(self, p: int) -> _Pane:
         if p not in self._ring:
@@ -354,7 +323,7 @@ class LiveSession:
         seq = jnp.asarray(batch.seq, jnp.int32)
         for p, m in self._masks_for(batch.row0, len(xb), valid):
             pane = self._pane(p)
-            pane.states, pane.est = _live_fold_jit(
+            pane.states, pane.est = fold.fold_chunk_jit(
                 pane.states, pane.est, xd, jax.device_put(m),
                 self._base_seed, seq, self._params, self._spec, self.B)
             pane.valid += int(m.sum())
@@ -440,49 +409,30 @@ class LiveSession:
 
     # -- crash safety ---------------------------------------------------
     def _save(self) -> None:
-        mgr = self.checkpoint
         state = {f"p{p}": (self._ring[p].states, self._ring[p].est)
                  for p in sorted(self._ring)}
-        mgr.save(self.counters.folded, state, extra={"cursor": {
-            "kind": "live", "fingerprint": self.fingerprint,
-            "next_seq": int(self._next_seq), "end_row": int(self._end_row),
-            "max_seen": int(self._max_seen), "dim": int(self._dim),
-            "lost": sorted(int(s) for s in self._lost),
-            "counters": dataclasses.asdict(self.counters),
-            "panes": {str(p): [int(self._ring[p].rows),
-                               int(self._ring[p].valid)]
-                      for p in sorted(self._ring)}}})
+        self.checkpoint.save(self.counters.folded, state, extra=cursor(
+            "live", self.fingerprint, next_seq=int(self._next_seq),
+            end_row=int(self._end_row), max_seen=int(self._max_seen),
+            dim=int(self._dim), lost=sorted(int(s) for s in self._lost),
+            counters=dataclasses.asdict(self.counters),
+            panes={str(p): [int(self._ring[p].rows),
+                            int(self._ring[p].valid)]
+                   for p in sorted(self._ring)}))
 
     def _restore(self) -> None:
-        mgr = self.checkpoint
-        cur = mgr.meta().get("cursor")
-        if cur is None or cur.get("kind") != "live":
-            raise ValueError(
-                f"checkpoint under {mgr.root} has no LiveSession cursor — "
-                "not a LiveSession checkpoint")
-        if cur["fingerprint"] != self.fingerprint:
-            raise ValueError(
-                "checkpoint fingerprint mismatch: the snapshot was taken "
-                "under a different (statistic, B, key, window) — resuming "
-                "it would silently produce a different estimator "
-                f"(checkpoint {cur['fingerprint'][:12]}…, "
-                f"run {self.fingerprint[:12]}…)")
+        def template(cur):
+            carry = jax.eval_shape(lambda: fold.empty_carry(
+                self.stat, self.B, int(cur["dim"])))
+            return {f"p{p}": carry for p in cur["panes"]}
+        state, cur = self.checkpoint.restore_cursor(
+            "live", self.fingerprint, template, "LiveSession",
+            "(statistic, B, key, window)")
         self._dim = int(cur["dim"])
-        template = {f"p{p}": jax.eval_shape(
-            lambda: (jax.vmap(lambda _: self.stat.init_state(self._dim))(
-                jnp.arange(self.B)),
-                self.stat.init_state(self._dim)))
-            for p in sorted(int(k) for k in cur["panes"])}
-        state, _ = mgr.restore(template)
-
-        def _fresh(tree):
-            return jax.tree_util.tree_map(
-                lambda a: jax.device_put(np.asarray(a)), tree)
         for p_str, (rows, valid) in cur["panes"].items():
-            p = int(p_str)
-            states, est = _fresh(state[f"p{p}"])
-            self._ring[p] = _Pane(states=states, est=est,
-                                  rows=int(rows), valid=int(valid))
+            states, est = fold.fresh(state[f"p{p_str}"])
+            self._ring[int(p_str)] = _Pane(states=states, est=est,
+                                           rows=int(rows), valid=int(valid))
         self._next_seq = int(cur["next_seq"])
         self._end_row = int(cur["end_row"])
         self._max_seen = int(cur["max_seen"])
