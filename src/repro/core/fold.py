@@ -16,7 +16,9 @@ import jax.numpy as jnp
 
 from repro import trace
 from repro.core import bootstrap     # which imports this module in turn
-from repro.core.reduce_api import Statistic, bind_params
+from repro.core.reduce_api import (HistogramState, Quantile, Statistic,
+                                   StatisticGroup, bind_params)
+from repro.kernels.fused_multi import ops as fm_ops
 
 
 def empty_states(stat: Statistic, B: int, dim: int):
@@ -43,26 +45,59 @@ def fold_rows(stat: Statistic, states, est, x: jax.Array, base_seed, i,
     """Fold the rows ``x`` (n, d) into ``(states, est)``; returns the pair.
 
     The estimate's update (``earl.estimate``) masks the rows by
-    ``valid_mask`` (exact 0.0/1.0), else by the prefix of ``n_valid`` rows,
-    else not at all; ``est=None`` skips it.  The resample fold
+    ``valid_mask`` (exact 0.0/1.0) and the prefix of ``n_valid`` rows,
+    where given; ``est=None`` skips it.  The resample fold
     (``earl.resample``) runs ``fused_resample_states`` on stream
     ``offset_seed(base_seed, i)`` with the same mask and merges the result
     into ``states``, or returns it when ``states`` is None.  Pure and
     jittable: callers trace it inside their own programs.
+
+    Where the resample fold is the fused Pallas kernel of a
+    ``StatisticGroup`` with a histogram slot (``estimate_rides_kernel``),
+    the estimate's histogram counts come out of that kernel's own dots,
+    exact and equal to ``update``'s scatter; its moment slots keep
+    ``update``.  Each trace of an estimate counts ``estimate_in_kernel``
+    then, or ``estimate_scatter`` where the estimate holds a histogram that
+    ``update`` builds.
     """
+    in_kernel = (est is not None and isinstance(stat, StatisticGroup)
+                 and fm_ops.estimate_rides_kernel(stat))
     if est is not None:
         mask = valid_mask
-        if mask is None and n_valid is not None:
-            mask = (jnp.arange(x.shape[0]) < n_valid).astype(jnp.float32)
+        if n_valid is not None:
+            prefix = (jnp.arange(x.shape[0]) < n_valid).astype(jnp.float32)
+            mask = prefix if mask is None else prefix * mask
+        if in_kernel:
+            trace.count(trace.ESTIMATE_IN_KERNEL)
+        elif _holds_histogram(est):
+            trace.count(trace.ESTIMATE_SCATTER)
         with jax.named_scope("earl.estimate"):
-            est = stat.update(est, x, mask)
+            est = (tuple(st if isinstance(s, Quantile)
+                         else s.update(st, x, mask)
+                         for s, st in zip(stat.slots, est))
+                   if in_kernel else stat.update(est, x, mask))
     with jax.named_scope("earl.resample"):
-        delta = bootstrap.fused_resample_states(
-            stat, bootstrap.offset_seed(base_seed, i), x, B,
-            n_valid=n_valid, valid_mask=valid_mask)
+        seed = bootstrap.offset_seed(base_seed, i)
+        if in_kernel:
+            delta, counts = fm_ops.fused_poisson_multi(
+                stat, seed, x, B, n_valid=n_valid, valid_mask=valid_mask,
+                backend=stat.backend, with_estimate=True)
+            counts = iter(counts)
+            est = tuple(HistogramState(st.counts + next(counts), st.lo, st.hi)
+                        if isinstance(s, Quantile) else st
+                        for s, st in zip(stat.slots, est))
+        else:
+            delta = bootstrap.fused_resample_states(
+                stat, seed, x, B, n_valid=n_valid, valid_mask=valid_mask)
         states = delta if states is None else \
             jax.vmap(stat.merge)(states, delta)
     return states, est
+
+
+def _holds_histogram(est) -> bool:
+    return any(isinstance(leaf, HistogramState)
+               for leaf in jax.tree_util.tree_leaves(
+                   est, is_leaf=lambda a: isinstance(a, HistogramState)))
 
 
 @partial(jax.jit, static_argnames=("spec", "B"), donate_argnums=(0, 1))

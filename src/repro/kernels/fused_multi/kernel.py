@@ -24,7 +24,9 @@ to running each member's dedicated fused kernel with the same seed.
 
 Grid: (B/bB, n/bn) with the contraction axis n LAST, so every output tile
 is revisited sequentially and accumulated in place (same discipline as
-weighted_stats / weighted_hist / kmeans_assign).
+weighted_stats / weighted_hist / kmeans_assign).  Both axes stay
+sequential: ``with_estimate``'s point-estimate counts are one block that
+every grid step of B-tile 0 accumulates into.
 """
 from __future__ import annotations
 
@@ -41,7 +43,7 @@ from repro.kernels.weighted_stats.kernel import _poisson_tile
 
 def _fm_kernel(scal_ref, x_ref, *refs, kinds, hist_nbins, hist_out_bins,
                d: int, block_b: int, block_n: int, use_tpu_prng: bool,
-               has_mask: bool = False):
+               has_mask: bool = False, with_estimate: bool = False):
     i = pl.program_id(0)        # B-tile index
     t = pl.program_id(1)        # n-tile index (contraction)
 
@@ -49,6 +51,7 @@ def _fm_kernel(scal_ref, x_ref, *refs, kinds, hist_nbins, hist_out_bins,
     in_refs = refs[:2 * n_hist]             # (lo, hi) per hist slot
     m_ref = refs[2 * n_hist] if has_mask else None
     out_refs = refs[2 * n_hist + (1 if has_mask else 0):]
+    est_refs = out_refs[len(out_refs) - n_hist:] if with_estimate else ()
 
     # ONE weight tile for every slot below — the whole point of the kernel.
     w = _poisson_tile(scal_ref[0], i, t, pl.num_programs(1),
@@ -57,6 +60,17 @@ def _fm_kernel(scal_ref, x_ref, *refs, kinds, hist_nbins, hist_out_bins,
                       valid=None if m_ref is None else m_ref[...])  # (bB, bn)
     x = x_ref[...].astype(jnp.float32)                        # (bn, dp)
     bn = x.shape[0]
+    if with_estimate:
+        # The point estimate's unit weights, as 8 more LHS rows of the
+        # B-tile-0 histogram dots: row 0 is the mask _poisson_tile applies
+        # to w, rows 1-7 are zero (an (8, 128)-aligned block).
+        row = jax.lax.broadcasted_iota(jnp.int32, (8, bn), 0)
+        col = t * block_n + jax.lax.broadcasted_iota(jnp.int32, (8, bn), 1)
+        unit = jnp.where(jnp.logical_and(row == 0, col < scal_ref[1]),
+                         1.0, 0.0)
+        if m_ref is not None:
+            unit = unit * m_ref[...]
+        w_unit = jnp.concatenate([w, unit], axis=0)      # (bB + 8, bn)
 
     oi = 0      # output-ref cursor
     hidx = 0    # hist-slot cursor
@@ -81,12 +95,18 @@ def _fm_kernel(scal_ref, x_ref, *refs, kinds, hist_nbins, hist_out_bins,
             out_bins = hist_out_bins[hidx]
             lo_ref, hi_ref = in_refs[2 * hidx:2 * hidx + 2]
             out_ref = out_refs[oi]
+            est_ref = est_refs[hidx] if with_estimate else None
             oi += 1
             hidx += 1
 
             @pl.when(t == 0)
             def _init_h(out_ref=out_ref):
                 out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
+
+            if with_estimate:
+                @pl.when(jnp.logical_and(i == 0, t == 0))
+                def _init_e(est_ref=est_ref):
+                    est_ref[...] = jnp.zeros(est_ref.shape, est_ref.dtype)
 
             idx = _bin_indices(x, lo_ref[...], hi_ref[...], nbins)
             mass = finite_mass_mask(x)
@@ -97,21 +117,39 @@ def _fm_kernel(scal_ref, x_ref, *refs, kinds, hist_nbins, hist_out_bins,
             for c in range(d):
                 onehot = ((idx[:, c:c + 1] == bins).astype(jnp.float32)
                           * mass[:, c:c + 1])                 # (bn, ob)
-                out_ref[:, c * out_bins:(c + 1) * out_bins] += jax.lax.dot(
-                    w, onehot, preferred_element_type=jnp.float32)
+                cols = slice(c * out_bins, (c + 1) * out_bins)
+                if not with_estimate:
+                    out_ref[:, cols] += jax.lax.dot(
+                        w, onehot, preferred_element_type=jnp.float32)
+                    continue
+
+                # The estimate's counts come out of the dot that already
+                # consumes this one-hot, once: on B-tile 0 alone.
+                @pl.when(i == 0)
+                def _dot_e(out_ref=out_ref, est_ref=est_ref, cols=cols,
+                           onehot=onehot):
+                    prod = jax.lax.dot(w_unit, onehot,
+                                       preferred_element_type=jnp.float32)
+                    out_ref[:, cols] += prod[:block_b]
+                    est_ref[:, cols] += prod[block_b:]
+
+                @pl.when(i != 0)
+                def _dot(out_ref=out_ref, cols=cols, onehot=onehot):
+                    out_ref[:, cols] += jax.lax.dot(
+                        w, onehot, preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("B", "kinds", "hist_nbins", "d_valid",
                                     "block_b", "block_n", "interpret",
-                                    "use_tpu_prng"))
+                                    "use_tpu_prng", "with_estimate"))
 def fused_poisson_multi_kernel(seed: jax.Array, n_valid: jax.Array,
                                values: jax.Array, hist_lo, hist_hi, B: int,
                                kinds, hist_nbins, d_valid: int,
                                block_b: int = 128, block_n: int = 512,
                                interpret: bool = True,
                                use_tpu_prng: bool = False,
-                               mask=None):
+                               mask=None, with_estimate: bool = False):
     """Raw kernel entry: shapes must already be padded (ops.py does this).
 
     values (n, dp) f32 with dp the 128-lane-padded dimension; ``hist_lo``/
@@ -124,7 +162,10 @@ def fused_poisson_multi_kernel(seed: jax.Array, n_valid: jax.Array,
     Returns the flat output tuple in slot order: a "moments" slot yields
     (w_tot (B, 1), s1 (B, dp), s2 (B, dp)); a "hist" slot yields
     (B, d_valid·out_bins) with out_bins = nbins lane-padded to 128 —
-    callers reshape and slice [..., :nbins].
+    callers reshape and slice [..., :nbins].  ``with_estimate`` appends,
+    per "hist" slot, an (8, d_valid·out_bins) block whose row 0 is the
+    unit-weight histogram of the rows under the same mask (the point
+    estimate's counts) and whose other rows are zero.
     """
     n, dp = values.shape
     assert B % block_b == 0 and n % block_n == 0, ((B, n), (block_b, block_n))
@@ -167,13 +208,21 @@ def fused_poisson_multi_kernel(seed: jax.Array, n_valid: jax.Array,
                                           lambda i, t: (i, 0)))
             out_shape.append(
                 jax.ShapeDtypeStruct((B, d_valid * ob), jnp.float32))
+    if with_estimate:
+        # constant index map: resident across the whole sequential grid
+        for ob in hist_out_bins:
+            out_specs.append(pl.BlockSpec((8, d_valid * ob),
+                                          lambda i, t: (0, 0)))
+            out_shape.append(
+                jax.ShapeDtypeStruct((8, d_valid * ob), jnp.float32))
 
     kern = functools.partial(_fm_kernel, kinds=tuple(kinds),
                              hist_nbins=tuple(hist_nbins),
                              hist_out_bins=hist_out_bins, d=d_valid,
                              block_b=block_b, block_n=block_n,
                              use_tpu_prng=use_tpu_prng,
-                             has_mask=mask is not None)
+                             has_mask=mask is not None,
+                             with_estimate=with_estimate)
     outs = pl.pallas_call(
         kern,
         grid=(B // block_b, n // block_n),

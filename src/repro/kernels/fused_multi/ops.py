@@ -50,6 +50,24 @@ def _kernel_slots(group) -> Tuple[bool, tuple]:
     return ok, hists
 
 
+def _backend(backend, eligible: bool) -> str:
+    """The lowering a ``backend`` of None resolves to: the kernel on a TPU
+    when the group is eligible, else the scan."""
+    if backend is not None:
+        return backend
+    return ("pallas" if jax.default_backend() == "tpu" and eligible
+            else "scan")
+
+
+def estimate_rides_kernel(group) -> bool:
+    """Whether ``fused_poisson_multi(group, ..., with_estimate=True)`` can
+    hand back the point estimate's histogram counts: a kernel-eligible
+    group with a histogram slot, lowered to the Pallas kernel."""
+    eligible, hists = _kernel_slots(group)
+    return (eligible and bool(hists)
+            and _backend(group.backend, eligible) != "scan")
+
+
 def _multi_scan(slots, seed, n_valid, xp, B: int, block_b: int,
                 block_n: int, maskp=None):
     """CPU lowering: one scan, one weight tile per step, every slot fed."""
@@ -103,7 +121,8 @@ def fused_poisson_tiled(stat, seed, values: jax.Array, B: int,
 def fused_poisson_multi(group, seed, values: jax.Array, B: int,
                         n_valid=None, valid_mask=None,
                         backend: str | None = None,
-                        block_b: int = 128, block_n: int = 512) -> Tuple:
+                        block_b: int = 128, block_n: int = 512,
+                        with_estimate: bool = False) -> Tuple:
     """Slot-ordered tuple of B-leading per-resample states for ``group``
     under one shared in-kernel Poisson(1) weight stream.
 
@@ -114,15 +133,19 @@ def fused_poisson_multi(group, seed, values: jax.Array, B: int,
     ``n_valid`` result bit for bit (see ``implicit_weight_tile``).  The
     result is what ``StatisticGroup.fused_poisson_states`` returns — its
     state pytree.
+
+    ``with_estimate=True`` (Pallas lowerings only, see
+    ``estimate_rides_kernel``) returns ``(states, counts)``: ``counts``
+    holds, per histogram slot, the (d, nbins) unit-weight counts of the
+    rows under the same masks, which the kernel takes from the dots that
+    build the resample sketches.
     """
     from repro.core.reduce_api import HistogramState, _MomentStatistic
     if values.ndim == 1:
         values = values[:, None]
     n, d = values.shape
     eligible, hist_slots = _kernel_slots(group)
-    if backend is None:
-        backend = ("pallas" if jax.default_backend() == "tpu" and eligible
-                   else "scan")
+    backend = _backend(backend, eligible)
     if backend not in ("scan", "pallas", "pallas_interpret"):
         raise ValueError(f"unknown fused_poisson_multi backend: {backend!r}")
     if backend != "scan" and not eligible:
@@ -130,6 +153,9 @@ def fused_poisson_multi(group, seed, values: jax.Array, B: int,
             "the fused_multi Pallas kernel covers moment/histogram slots "
             "only; groups with KMeansStep or custom statistics use "
             "backend='scan' (same shared weight tiles, via tile_update)")
+    if with_estimate and (backend == "scan" or not hist_slots):
+        raise ValueError("with_estimate needs the Pallas kernel and a "
+                         "histogram slot (estimate_rides_kernel)")
     if n_valid is None:
         n_valid = n
 
@@ -161,7 +187,8 @@ def fused_poisson_multi(group, seed, values: jax.Array, B: int,
         hist_nbins=tuple(s.nbins for s in hist_slots), d_valid=d,
         block_b=bb, block_n=bn, interpret=(backend != "pallas"),
         use_tpu_prng=(backend == "pallas"),
-        mask=None if mp is None else mp[None, :])
+        mask=None if mp is None else mp[None, :],
+        with_estimate=with_estimate)
 
     states, oi = [], 0
     for slot, kind in zip(group.slots, kinds):
@@ -178,4 +205,8 @@ def fused_poisson_multi(group, seed, values: jax.Array, B: int,
                 counts=counts,
                 lo=jnp.full((B, d), slot.lo, jnp.float32),
                 hi=jnp.full((B, d), slot.hi, jnp.float32)))
-    return tuple(states)
+    if not with_estimate:
+        return tuple(states)
+    counts = tuple(e[0].reshape(d, -1)[:, :s.nbins]
+                   for e, s in zip(outs[oi:], hist_slots))
+    return tuple(states), counts

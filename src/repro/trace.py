@@ -25,6 +25,11 @@ dispatches: SSABE's B̂ ladder, each of its ``l`` n-steps and each σ round
 (``poisson_delta_result``), 1 + l + rounds a session, each read back in one
 ``host_sync``; ``result_traces`` counts the times JAX traces their bodies,
 which a warm query does not.
+``estimate_in_kernel`` counts the times ``core.fold.fold_rows`` traces a
+point estimate whose histogram counts come out of the fused group kernel,
+and ``estimate_scatter`` those whose histogram ``update`` builds (a
+scatter-add, or the histogram kernel of a Quantile's own backend); a warm
+program adds to neither.
 """
 from __future__ import annotations
 
@@ -41,6 +46,8 @@ MESH_TRACES = "mesh_traces"
 MESH_PSUM_BYTES = "mesh_psum_bytes"
 RESULT_STEPS = "result_steps"
 RESULT_TRACES = "result_traces"
+ESTIMATE_IN_KERNEL = "estimate_in_kernel"
+ESTIMATE_SCATTER = "estimate_scatter"
 
 _lock = threading.Lock()
 _counts: Dict[str, int] = {}

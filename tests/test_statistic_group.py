@@ -29,7 +29,8 @@ from repro.core import (EarlSession, GroupAccuracyReport, KMeansStep, Mean,
 from repro.core.bootstrap import fused_resample_states, seed_from_key
 from repro.core.delta import (poisson_delta_extend, poisson_delta_init,
                               poisson_delta_result)
-from repro.core.reduce_api import (_ArrayParam, bind_params, split_params)
+from repro.core.reduce_api import (HistogramState, _ArrayParam, bind_params,
+                                   split_params)
 from repro.core.ssabe import ssabe
 from repro.kernels.fused_multi import ops as fm_ops
 from test_matrix_free import _max_intermediate_size, _walk_shapes  # noqa
@@ -349,3 +350,127 @@ class TestGroupAccuracyReport:
         assert rep.cv == max(m.cv for m in rep.members)
         assert rep.se == max(m.se for m in rep.members)
         assert len(rep.ci_lo) == 2 and len(rep.cvs) == 2
+
+
+# ---------------------------------------------------------------------------
+# The point estimate's histogram out of the fused kernel (core/fold.py)
+# ---------------------------------------------------------------------------
+def _plus_two(tree):
+    """Every moment and count of ``tree`` plus 2; the sketch ranges kept."""
+    def add(a):
+        if isinstance(a, HistogramState):
+            return HistogramState(a.counts + 2.0, a.lo, a.hi)
+        return a + 2.0
+    return jax.tree_util.tree_map(
+        add, tree, is_leaf=lambda a: isinstance(a, HistogramState))
+
+
+def _estimate_group(two_hists: bool, backend="pallas_interpret"):
+    members = [Mean(), Quantile(0.5, nbins=256, lo=6.0, hi=14.0), Std()]
+    if two_hists:
+        members.append(Quantile(0.9, nbins=200, lo=0.0, hi=20.0))
+    return StatisticGroup(members, backend=backend)
+
+
+def _rough_rows(n, d, seed=0):
+    """N(10, 2) rows with NaN, ±inf and values outside both sketch
+    ranges."""
+    x = np.random.default_rng(seed).normal(10, 2, (n, d)).astype(np.float32)
+    x[3, 0], x[8, d - 1], x[13, 0] = np.nan, np.inf, -np.inf
+    x[21, d - 1], x[34, 0] = 100.0, -50.0
+    return jnp.asarray(x)
+
+
+@pytest.mark.parametrize("two_hists", [False, True], ids=["1hist", "2hists"])
+@pytest.mark.parametrize("B", [20, 300], ids=["B<block_b", "B%block_b"])
+@pytest.mark.parametrize("d", [1, 3])
+@pytest.mark.parametrize("mask", ["holes", "ragged"])
+def test_fold_estimate_in_kernel_is_bit_equal_to_the_scatter(mask, d, B,
+                                                             two_hists):
+    """fold_rows takes the estimate's histogram counts from the group
+    kernel's dots: they, the estimate's moments and the resample states are
+    bit-equal to the estimate's ``update`` (the scatter-add) beside the
+    kernel without the estimate."""
+    from repro.core import fold
+    from repro.core.bootstrap import offset_seed
+    n = 700
+    g = _estimate_group(two_hists)
+    x = _rough_rows(n, d)
+    rng = np.random.default_rng(1)
+    if mask == "holes":
+        vm = jnp.asarray((rng.random(n) > 0.3).astype(np.float32))
+        kw, est_mask = dict(valid_mask=vm), vm
+        # interior holes, some falling inside a row with NaN or inf
+        assert 0 < float(vm[:n // 2].sum()) < n // 2
+    else:
+        kw = dict(n_valid=jnp.int32(611))
+        est_mask = (jnp.arange(n) < 611).astype(jnp.float32)
+    # running states and estimate that already hold counts and moments
+    states0, est0 = _plus_two(fold.empty_carry(g, B, d))
+
+    states, est = jax.jit(lambda s, e, x: fold.fold_rows(
+        g, s, e, x, 77, 3, B, **kw))(states0, est0, x)
+
+    want_est = g.update(est0, x, est_mask)
+    delta = fused_resample_states(g, offset_seed(77, 3), x, B, **kw)
+    want_states = jax.vmap(g.merge)(states0, delta)
+    for got, want in zip(jax.tree_util.tree_leaves((est, states)),
+                         jax.tree_util.tree_leaves((want_est, want_states))):
+        assert got.shape == want.shape
+        assert np.array_equal(np.asarray(got), np.asarray(want),
+                              equal_nan=True)
+    hist_slots = [st for st in est if hasattr(st, "counts")]
+    assert len(hist_slots) == (2 if two_hists else 1)
+    for st in hist_slots:      # NaN mass dropped, ±inf kept in edge bins
+        got = np.asarray(st.counts).sum(axis=1) - 2.0 * st.counts.shape[1]
+        nan_rows = np.isnan(np.asarray(x)) * np.asarray(est_mask)[:, None]
+        np.testing.assert_array_equal(
+            got, float(np.asarray(est_mask).sum()) - nan_rows.sum(axis=0))
+
+
+@pytest.mark.parametrize("backend,members,want", [
+    ("pallas_interpret", None, {"estimate_in_kernel": 1,
+                                "estimate_scatter": 0}),
+    ("scan", None, {"estimate_in_kernel": 0, "estimate_scatter": 1}),
+    ("pallas_interpret", (Mean(), Std()), {"estimate_in_kernel": 0,
+                                           "estimate_scatter": 0}),
+], ids=["paper-jobs-kernel", "paper-jobs-scan", "moments-only"])
+def test_fold_chunk_counts_where_the_estimate_histogram_runs(backend,
+                                                             members, want):
+    """One trace of the stream step counts the estimate once, by where its
+    histogram is built; a warm call counts nothing."""
+    from repro import trace
+    from repro.core import fold
+    g = (_estimate_group(False, backend) if members is None
+         else StatisticGroup(members, backend=backend))
+    spec, params = split_params(g)
+    n, B = 512, 8
+    x = _rough_rows(n, 1)
+    vi = jnp.asarray((np.arange(n) % 7 != 0).astype(np.float32))
+
+    def step():
+        states, est = fold.fresh(fold.empty_carry(g, B, 1))
+        before = trace.counts()
+        fold.fold_chunk_jit(states, est, x, vi, jnp.int32(5), jnp.int32(0),
+                            params, spec, B)
+        after = trace.counts()
+        return {k: after.get(k, 0) - before.get(k, 0) for k in want}
+
+    assert step() == want
+    assert step() == {k: 0 for k in want}
+
+
+def test_delta_extend_folds_no_estimate():
+    """The ad-hoc Δ extends fold with est=None: their estimate is the
+    extend's own update, counted by neither counter."""
+    from repro import trace
+    g = _estimate_group(False)
+    pd = poisson_delta_init(g, 8, 1, jax.random.PRNGKey(0),
+                            backend="fused_rng")
+    before = trace.counts()
+    pd = poisson_delta_extend(pd, _rough_rows(300, 1))
+    jax.block_until_ready(pd.states)
+    after = trace.counts()
+    assert {k: after.get(k, 0) - before.get(k, 0)
+            for k in ("estimate_in_kernel", "estimate_scatter")} == \
+        {"estimate_in_kernel": 0, "estimate_scatter": 0}

@@ -16,6 +16,7 @@ every test file.
 from __future__ import annotations
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -117,6 +118,29 @@ def test_multi_mean_median_std(one_chip):
     group = StatisticGroup((Mean(), Median(lo=-4.0, hi=4.0), Std()))
     fn = lambda s, x: fused_poisson_multi(group, s, x, B, backend="pallas")
     _compile(fn, one_chip, SEED, _x(1))
+
+
+def test_scan_stream_step_folds_the_estimate_in_the_kernel(one_chip):
+    """The scan cell's step: the paper-jobs group over a masked 2^20-row
+    chunk.  The point estimate's median sketch comes out of the group
+    kernel; no scatter-add is left in the program."""
+    from repro.core.fold import empty_carry, fold_chunk_jit
+    from repro.core.reduce_api import Quantile, split_params
+    group = StatisticGroup((Mean(), Quantile(0.5, nbins=2048, lo=9.0,
+                                             hi=11.0), Std()),
+                           backend="pallas")
+    spec, params = split_params(group)
+    carry = jax.tree_util.tree_map(
+        lambda a: _sds(a.shape, a.dtype, one_chip),
+        jax.eval_shape(lambda: empty_carry(group, B, 1)))
+    text = fold_chunk_jit.lower(
+        *carry, _sds((N, 1), jnp.float32, one_chip),
+        _sds((N,), jnp.float32, one_chip), _sds((), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip), params, spec, B).compile().as_text()
+    assert "tpu_custom_call" in text, "no Pallas kernel in the program"
+    # an HLO scatter instruction, not a frame name in the metadata
+    assert re.search(r"\bscatter\(", text) is None, \
+        "the estimate's scatter-add is back"
 
 
 def test_poisson_counts(one_chip):
